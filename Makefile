@@ -32,14 +32,14 @@ bench-smoke:
 		--out BENCH_faults.json
 	PYTHONPATH=src $(PY) -m benchmarks.notification_matrix --smoke \
 		--out BENCH_notifications.json
-	PYTHONPATH=src $(PY) -m benchmarks.perf_sim --smoke --require-jax \
+	PYTHONPATH=src $(PY) -m benchmarks.perf_sim --smoke \
 		--out /tmp/bench_sim_smoke.json
 
 # simulator phase-kernel perf trajectory: write + schema-check
 # BENCH_sim.json (paper scale — the committed numbers; see
 # docs/performance.md for the 50k/120k crossover discussion)
 bench-perf:
-	PYTHONPATH=src $(PY) -m benchmarks.perf_sim --full --require-jax \
+	PYTHONPATH=src $(PY) -m benchmarks.perf_sim --full \
 		--out BENCH_sim.json
 	$(PY) scripts/ci_lint.py --bench
 

@@ -10,7 +10,8 @@ same traffic pattern, phase after phase):
                   per phase; seed-for-seed identical to reference);
   * numpy_plan  — fast path + PhasePlan reuse (the steady-state mode for
                   repeated collective rounds);
-  * jax[_plan]  — the jitted backend (skipped when jax is unusable).
+  * jax_plan    — the jitted backend; always run, and the run fails
+                  unless the jitted pipeline really dispatched.
 
 Emits the ``name,us_per_call,derived`` CSV rows all benchmarks print,
 plus ``BENCH_sim.json`` at schema ``bench_sim/v2`` (documented in
@@ -18,8 +19,9 @@ docs/performance.md): per-backend phases/s, flows/s, per-stage timings,
 and ``compile_s`` — the one-time first-call cost (jit tracing +
 compilation on jax; cache warmup elsewhere) measured separately so
 steady-state ``phase_s`` never includes it.  ``--smoke`` shrinks the
-phase for CI; ``--require-jax`` makes a silent jax->numpy fallback a
-hard error (asserts the jitted pipeline actually dispatched).
+phase for CI.  Every number is a timing on whatever device jax finds
+(``jax_device`` in the JSON names it).  Run as a script, it turns on
+the persistent compilation cache (``repro.compat.enable_compile_cache``).
 `make bench-perf` runs it and schema-checks the JSON via
 ``scripts/ci_lint.py --bench``.
 """
@@ -31,12 +33,15 @@ import json
 import pathlib
 import time
 
+import jax
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compat import enable_compile_cache
 from repro.core.strategies import RoutingMode
 from repro.dragonfly import (DragonflySimulator, DragonflyTopology,
                              SimParams, TopologyParams)
+from repro.dragonfly.jax_backend import PIPELINE_CALLS
 from repro.dragonfly.reference import reference_run_phase
 from repro.dragonfly.routing import RoutingPolicy
 from repro.dragonfly.topology import make_allocation
@@ -44,7 +49,7 @@ from repro.dragonfly.topology import make_allocation
 SCHEMA = "bench_sim/v2"
 
 
-def _phase_inputs(topo: DragonflyTopology, n_flows: int, seed: int = 42):
+def phase_inputs(topo: DragonflyTopology, n_flows: int, seed: int = 42):
     """A pareto-sized random many-to-many phase (alltoall-ish shape)."""
     rng = np.random.default_rng(seed)
     src = rng.integers(0, topo.params.n_nodes, size=n_flows)
@@ -82,26 +87,16 @@ def _time_backend(topo, src, dst, size, alloc, *, phases, backend="numpy",
     return dt, compile_s, stages, res
 
 
-def run(n_flows: int, phases: int, out_path: str | None,
-        require_jax: bool = False):
+def run(n_flows: int, phases: int, out_path: str | None):
     topo = DragonflyTopology(TopologyParams(n_groups=12))
-    src, dst, size = _phase_inputs(topo, n_flows)
+    src, dst, size = phase_inputs(topo, n_flows)
     alloc = make_allocation(topo, min(64, n_flows), spread="inter_groups",
                             seed=3)
     arms = [("reference", dict(reference=True)),
             ("numpy", dict(backend="numpy")),
-            ("numpy_plan", dict(backend="numpy", use_plans=True))]
-    from repro.compat.runtime import resolve_backend
-    jax_ok = resolve_backend("jax") == "jax"
-    if require_jax and not jax_ok:
-        raise RuntimeError("--require-jax: jax backend unavailable "
-                           "(resolve_backend fell back to numpy)")
-    if jax_ok:
-        arms.append(("jax_plan", dict(backend="jax", use_plans=True)))
-
-    if jax_ok:
-        from repro.dragonfly.jax_backend import PIPELINE_CALLS
-        calls_before = dict(PIPELINE_CALLS)
+            ("numpy_plan", dict(backend="numpy", use_plans=True)),
+            ("jax_plan", dict(backend="jax", use_plans=True))]
+    calls_before = sum(PIPELINE_CALLS.values())
     results = {}
     checks = {}
     for name, kw in arms:
@@ -117,13 +112,9 @@ def run(n_flows: int, phases: int, out_path: str | None,
         checks[name] = res
         emit(f"perf_sim.{name}.phase", dt * 1e6,
              f"flows_per_s={n_flows / dt:.0f} compile_s={compile_s:.3f}")
-    if require_jax:
-        from repro.dragonfly.jax_backend import PIPELINE_CALLS
-        dispatched = sum(PIPELINE_CALLS.values()) \
-            - sum(calls_before.values())
-        if dispatched <= 0:
-            raise RuntimeError("--require-jax: jax arm never dispatched "
-                               "the jitted pipeline (silent fallback?)")
+    if sum(PIPELINE_CALLS.values()) <= calls_before:
+        raise RuntimeError("jax_plan arm never dispatched the jitted "
+                           "pipeline")
 
     # seed-equivalence sanity: the numpy fast path must replay the
     # reference bit-for-bit on the same seed (the golden-trace property)
@@ -138,11 +129,8 @@ def run(n_flows: int, phases: int, out_path: str | None,
     for k, v in speedups.items():
         emit(f"perf_sim.speedup.{k}", v, "x")
 
-    device = None
-    if jax_ok:
-        import jax
-        device = {"backend": jax.default_backend(),
-                  "n_devices": int(jax.device_count())}
+    device = {"backend": jax.default_backend(),
+              "n_devices": int(jax.device_count())}
     doc = {
         "schema": SCHEMA,
         "flows": int(n_flows),
@@ -160,11 +148,11 @@ def run(n_flows: int, phases: int, out_path: str | None,
 
 
 def main(full: bool = False, smoke: bool = False,
-         out: str | None = None, require_jax: bool = False) -> dict:
+         out: str | None = None) -> dict:
     n_flows, phases = (50_000, 5) if not smoke else (4_000, 3)
     if full:
         n_flows, phases = 120_000, 5
-    return run(n_flows, phases, out, require_jax=require_jax)
+    return run(n_flows, phases, out)
 
 
 if __name__ == "__main__":
@@ -173,10 +161,8 @@ if __name__ == "__main__":
                     help="small CI pass (4k flows)")
     ap.add_argument("--full", action="store_true",
                     help="paper-scale pass (120k flows)")
-    ap.add_argument("--require-jax", action="store_true",
-                    help="fail instead of silently skipping the jax arm")
     ap.add_argument("--out", default="BENCH_sim.json",
                     help="output JSON path (default: BENCH_sim.json)")
     args = ap.parse_args()
-    main(full=args.full, smoke=args.smoke, out=args.out,
-         require_jax=args.require_jax)
+    enable_compile_cache()
+    main(full=args.full, smoke=args.smoke, out=args.out)

@@ -9,6 +9,8 @@ import argparse
 import sys
 import time
 
+from repro.compat import enable_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -26,6 +28,7 @@ def main(argv=None) -> None:
                          "interference), e.g. 'dragonfly_plus:p=4,"
                          "a_leaf=8,a_spine=8,h=2,g=17' (docs/topology.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from benchmarks import (fig3_allocation, fig4_fig5_hostnoise,
                             fig7_routing_pingpong, fig8_microbench,

@@ -28,14 +28,6 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 _LINK_RE = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
 _EXTERNAL = ("http://", "https://", "mailto:")
 
-# version-drifting jax symbols that must be reached via repro.compat
-# (docs/compat.md); the shim package and its tests are the only homes
-_BARE_JAX_RE = re.compile(
-    r"jax\.set_mesh|jax\.sharding\.AxisType"
-    r"|jax\.sharding\.get_abstract_mesh|jax\.shard_map"
-    r"|jax\.experimental\.shard_map")
-_SHIM_EXEMPT = ("src/repro/compat/", "tests/test_compat.py")
-
 
 def lint_style() -> list:
     bad = []
@@ -70,24 +62,6 @@ def lint_docs_links() -> list:
                 if not (page.parent / path).resolve().exists():
                     bad.append(f"{page.relative_to(ROOT)}:{i}: "
                                f"broken link -> {target}")
-    return bad
-
-
-def lint_bare_jax_calls() -> list:
-    """No version-gated jax API used outside the repro.compat shims."""
-    bad = []
-    for root in ("src", "benchmarks", "examples", "tests", "scripts"):
-        for p in (ROOT / root).rglob("*.py"):
-            if "__pycache__" in p.parts:
-                continue
-            rel = p.relative_to(ROOT).as_posix()
-            if rel.startswith(_SHIM_EXEMPT[0]) or rel == _SHIM_EXEMPT[1]:
-                continue
-            for i, line in enumerate(p.read_text().splitlines(), 1):
-                m = _BARE_JAX_RE.search(line)
-                if m:
-                    bad.append(f"{rel}:{i}: bare {m.group(0)} — go through "
-                               f"repro.compat (docs/compat.md)")
     return bad
 
 
@@ -376,9 +350,8 @@ def lint_topology_invariants() -> list:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--docs", action="store_true",
-                    help="check README/docs links, tracked __pycache__, "
-                         "and bare version-gated jax calls instead of "
-                         "Python style")
+                    help="check README/docs links and tracked "
+                         "__pycache__ instead of Python style")
     ap.add_argument("--bench", action="store_true",
                     help="require BENCH_sim.json and check its schema")
     ap.add_argument("--topology", action="store_true",
@@ -394,7 +367,7 @@ def main(argv=None) -> int:
                + lint_bench_notifications_schema())
     elif args.docs:
         bad = (lint_docs_links() + lint_tracked_pycache()
-               + lint_bare_jax_calls() + lint_bench_schema()
+               + lint_bench_schema()
                + lint_bench_interference_schema()
                + lint_bench_faults_schema()
                + lint_bench_notifications_schema())

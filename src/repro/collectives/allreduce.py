@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def _flatten_pad(x, n):
     flat = x.reshape(-1)
@@ -82,7 +80,7 @@ def grad_allreduce(grads, mesh, *, mode, pod_axis: str = "pod",
         return P()  # per-device partial sums along the dp axes
 
     in_specs = jax.tree_util.tree_map(spec_for, grads)
-    return compat.shard_map(
+    return jax.shard_map(
         lambda g: jax.tree_util.tree_map(reduce_leaf, g),
         mesh=mesh, in_specs=(in_specs,), out_specs=in_specs,
         check_vma=False,

@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.collectives.modes import CollectiveMode
 from repro.models.common import ModelConfig, activation, dp_spec, mesh_axes
 from repro.models.mlp import mlp
@@ -80,7 +79,7 @@ def moe_ep(p, x, cfg: ModelConfig, *,
     from jax.sharding import PartitionSpec as P
 
     E, k = cfg.n_experts, cfg.top_k
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp_tuple = dp if isinstance(dp, tuple) else ((dp,) if dp else ())
     n_dp = 1
     for a in dp_tuple:
@@ -131,7 +130,7 @@ def moe_ep(p, x, cfg: ModelConfig, *,
 
     w = p  # param dict
     E_loc = E // ep
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp if dp else None, None, None), P(), P(ep_axis),
                   P(ep_axis), P(ep_axis), P()),

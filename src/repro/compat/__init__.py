@@ -1,40 +1,22 @@
-"""repro.compat — one version-gated shim layer over the jax API drift.
-
-The reproduction is written against the jax>=0.7 mesh/sharding surface;
-the container ships jax 0.4.37.  Every call site that would differ
-between the two goes through this package instead of jax directly:
+"""repro.compat — the few helpers the repo layers over the installed jax.
 
     from repro import compat
 
     mesh = compat.make_mesh((4, 4), ("data", "model"))   # Auto axes
-    with compat.set_mesh(mesh):                          # set_mesh / ctx
+    with jax.set_mesh(mesh):
         sizes = compat.abstract_axis_sizes()             # {"data": 4, ...}
-    fn = compat.shard_map(body, mesh=mesh, in_specs=..., out_specs=...,
-                          check_vma=False)               # check_rep on 0.4
-    if compat.jax_version_at_least("0.7"):
-        ...
 
-See docs/compat.md for the full version matrix.  Dispatch happens at
-call time on the `repro.compat.version.HAS_*` feature flags, so tests
-monkeypatch a flag plus a fake jax attribute to exercise the modern
-branch on an old jax (tests/test_compat.py).
+``compat.runtime`` holds the simulator's accelerator helpers: TPU
+detection, the ``SimParams.pallas_kernel`` resolution and the placement
+of JAX's persistent compilation cache.  Everything else calls jax
+directly (docs/compat.md).
 """
 
-from repro.compat.compilation import cost_analysis, jit_compiled
-from repro.compat.mesh import (abstract_axis_sizes, axis_types,
-                               get_abstract_mesh, make_mesh, set_mesh)
-from repro.compat.runtime import (jax_available, on_tpu, pallas_available,
-                                  resolve_backend, resolve_pallas_kernel)
-from repro.compat.shardmap import shard_map
-from repro.compat.version import (JAX_VERSION, describe,
-                                  jax_version_at_least, parse_version)
+from repro.compat.mesh import abstract_axis_sizes, make_mesh
+from repro.compat.runtime import (enable_compile_cache, on_tpu,
+                                  resolve_pallas_kernel)
 
 __all__ = [
-    "JAX_VERSION", "jax_version_at_least", "parse_version", "describe",
-    "abstract_axis_sizes", "axis_types", "get_abstract_mesh",
-    "make_mesh", "set_mesh",
-    "shard_map",
-    "cost_analysis", "jit_compiled",
-    "jax_available", "pallas_available", "resolve_backend",
-    "on_tpu", "resolve_pallas_kernel",
+    "abstract_axis_sizes", "make_mesh",
+    "enable_compile_cache", "on_tpu", "resolve_pallas_kernel",
 ]

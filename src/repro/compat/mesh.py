@@ -1,80 +1,24 @@
-"""Mesh construction / activation shims.
+"""Mesh helpers over the installed jax.
 
-jax>=0.7 meshes carry per-axis `AxisType`s, are activated with
-`jax.set_mesh`, and are observable from anywhere via
-`jax.sharding.get_abstract_mesh()`.  jax 0.4.x has none of that: meshes
-are typeless, activation is the `Mesh` context manager, and the active
-mesh lives in the pxla thread-resources env.  These shims present the
-modern surface on both.
+``jax.make_mesh`` gives every axis ``AxisType.Explicit`` by default;
+the sharding rules of the language-model stack are written for
+auto-sharded axes, so meshes are built through :func:`make_mesh`.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-from repro.compat import version as _v
-
-
-def axis_types(n: int):
-    """(AxisType.Auto,) * n where AxisType exists, else None.
-
-    None means "build the mesh without the kwarg" — Auto is the only
-    behaviour jax 0.4.x has, so omission is the faithful fallback.
-    """
-    if _v.HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """`jax.make_mesh` with Auto axis_types whenever jax knows them."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    types = axis_types(len(tuple(axis_names)))
-    if types is not None:
-        kwargs["axis_types"] = types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Context manager activating `mesh` (jax.set_mesh / Mesh ctx).
-
-    On jax 0.4.x the `Mesh` context manager is the activation
-    primitive: it installs the mesh in the thread-resources env, which
-    is what `with_sharding_constraint` and `get_abstract_mesh()` (our
-    fallback below) read.
-    """
-    if _v.HAS_SET_MESH:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-def get_abstract_mesh():
-    """The active mesh, or an empty mesh outside any `set_mesh`.
-
-    jax 0.4.x has no AbstractMesh tracking; the physical mesh from the
-    thread-resources env answers the same questions (`axis_names`,
-    `shape`) and is accepted by `compat.shard_map`.
-    """
-    if _v.HAS_GET_ABSTRACT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    from jax.interpreters.pxla import thread_resources
-    return thread_resources.env.physical_mesh
+    """`jax.make_mesh` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def abstract_axis_sizes() -> dict:
-    """{axis_name: size} of the active mesh ({} outside set_mesh)."""
-    try:
-        mesh = get_abstract_mesh()
-    except Exception:  # pragma: no cover - defensive on exotic versions
-        return {}
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return {}
+    """{axis_name: size} of the active mesh ({} outside ``jax.set_mesh``)."""
+    mesh = jax.sharding.get_abstract_mesh()
     return {a: mesh.shape[a] for a in mesh.axis_names}

@@ -1,58 +1,28 @@
-"""Optional-accelerator feature detection for the simulator backends.
+"""Accelerator runtime helpers for the simulator's jax backend.
 
-The Dragonfly simulator's ``SimParams.backend = "jax"`` fast path needs
-a working jax (and, for the TPU segment-sum kernel, Pallas).  Feature
-detection lives here — sibling to the version shims — so the simulator
-itself never imports jax at module load and degrades to NumPy cleanly
-on containers without a usable accelerator stack (docs/performance.md).
+``on_tpu`` and ``resolve_pallas_kernel`` decide how the jitted phase
+engine (``SimParams.backend = "jax"``) reduces its link loads.
+``enable_compile_cache`` places JAX's persistent compilation cache for
+the entry points that run on the chip (``chip_smoke.py``,
+``benchmarks/perf_sim.py``, ``benchmarks/run.py``).  There is no
+fallback here: a jax backend that cannot run raises where it is used.
 """
 
 from __future__ import annotations
 
-import warnings
+import os
+import pathlib
 
-_JAX_OK: bool | None = None
-_PALLAS_OK: bool | None = None
-_WARNED_FALLBACK = False
+import jax
 
-
-def jax_available() -> bool:
-    """Can `import jax` and build a trivial jitted function?"""
-    global _JAX_OK
-    if _JAX_OK is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            jax.jit(lambda x: x + 1)(jnp.zeros(()))
-            _JAX_OK = True
-        except Exception:            # noqa: BLE001 — any failure = absent
-            _JAX_OK = False
-    return _JAX_OK
-
-
-def pallas_available() -> bool:
-    """Is jax.experimental.pallas importable (TPU kernel path)?"""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        if not jax_available():
-            _PALLAS_OK = False
-        else:
-            try:
-                from jax.experimental import pallas  # noqa: F401
-
-                _PALLAS_OK = True
-            except Exception:        # noqa: BLE001
-                _PALLAS_OK = False
-    return _PALLAS_OK
+#: the one in-checkout directory of the persistent compilation cache
+#: when ``JAX_COMPILATION_CACHE_DIR`` is unset (.gitignore lists it).
+#: Fixed, never per-process: the path is part of every cache key.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def on_tpu() -> bool:
-    """Is the default jax backend a TPU?  False when jax is unusable."""
-    if not jax_available():
-        return False
-    import jax
-
+    """Is the default jax backend a TPU?"""
     return jax.default_backend() == "tpu"
 
 
@@ -63,10 +33,10 @@ PALLAS_KNOBS = ("auto", "on", "off")
 def resolve_pallas_kernel(knob: str) -> bool:
     """Resolve the ``SimParams.pallas_kernel`` knob to use-kernel or not.
 
-    "auto" uses the Pallas segment-sum only where it can win — on TPU
-    (interpret-mode Pallas is far slower than jax.ops.segment_sum on
-    CPU); "on" forces it everywhere (interpret mode off-TPU — the parity
-    testing path); "off" never uses it, even on TPU."""
+    "auto" uses the Pallas segment-sum on a TPU, compiled, and
+    ``jax.ops.segment_sum`` elsewhere; "on" forces the kernel everywhere
+    (in interpret mode off the chip — the parity-testing path); "off"
+    never uses it, even on a TPU."""
     if knob == "on":
         return True
     if knob == "off":
@@ -74,27 +44,18 @@ def resolve_pallas_kernel(knob: str) -> bool:
     if knob != "auto":
         raise ValueError(f"unknown pallas_kernel knob {knob!r}; "
                          f"expected one of {PALLAS_KNOBS}")
-    return pallas_available() and on_tpu()
+    return on_tpu()
 
 
-def resolve_backend(requested: str) -> str:
-    """Map a requested simulator backend to a usable one.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-    "numpy" is always usable; "jax" degrades to "numpy" (warning once)
-    when jax is missing or broken.  Unknown names raise."""
-    if requested == "numpy":
-        return "numpy"
-    if requested != "jax":
-        raise ValueError(f"unknown simulator backend {requested!r}; "
-                         f"expected 'numpy' or 'jax'")
-    # the jitted pipeline imports the Pallas segment-sum kernel at module
-    # load, so a jax without pallas is just as unusable as no jax
-    if jax_available() and pallas_available():
-        return "jax"
-    global _WARNED_FALLBACK
-    if not _WARNED_FALLBACK:
-        warnings.warn("simulator backend 'jax' unavailable in this "
-                      "environment; falling back to 'numpy'",
-                      RuntimeWarning, stacklevel=2)
-        _WARNED_FALLBACK = True
-    return "numpy"
+    A ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: JAX reads
+    it itself and no other directory is set.  Otherwise the cache lives
+    in :data:`COMPILE_CACHE_DIR`.  Call it from an entry point before
+    the first compile; importing a module never turns the cache on."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

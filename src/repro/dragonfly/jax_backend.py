@@ -2,7 +2,7 @@
 
 ``SimParams.backend = "jax"`` routes the score -> spray -> feedback
 fixed point -> observables pipeline of ``run_phase`` through ONE jitted
-function whose feedback loop is a ``lax.fori_loop`` — iterations never
+function whose feedback loop is a ``lax.scan`` — iterations never
 touch the host, and compile time no longer scales with
 ``route_feedback_iters``.  Three things make the path device-resident:
 
@@ -19,9 +19,9 @@ touch the host, and compile time no longer scales with
     already covers topology spec + fault epoch + notify epoch, so a
     stale bundle cannot outlive its plan.  Per phase only the small
     per-link state, the background-flow slivers, and the Gumbel noise
-    block move host->device — the noise block is donated
-    (``donate_argnums`` via ``repro.compat.jit_compiled``) so XLA can
-    reuse its buffer for the outputs.
+    block move host->device.  The noise block is not donated: no
+    output has its [iters, n, ncand] shape, so XLA could never reuse
+    its buffer (a TPU v5e reports such a donation as unusable).
 
   * **Stable shapes.** Background flows redraw candidates per phase,
     which used to change the (link, flow-cand) pair-list length P every
@@ -52,41 +52,46 @@ like the NumPy backend and matches it within float32 tolerance
 from __future__ import annotations
 
 import functools
-import warnings
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat.compilation import jit_compiled
 from repro.compat.runtime import on_tpu, resolve_pallas_kernel
 from repro.kernels.segment_sum.ref import segment_sum_ref
 from repro.kernels.segment_sum.segment_sum import segment_sum_pallas
 
-# CPU/GPU backends cannot always alias the donated Gumbel block into an
-# output buffer; the fallback (a silent copy) is exactly the pre-donation
-# behavior, so the warning is noise here.
-warnings.filterwarnings("ignore",
-                        message="Some donated buffers were not usable")
-
 #: diagnostics: executed-pipeline counters ("single"/"batched" jitted
-#: dispatches).  Tests and perf_sim assert on deltas to prove the jax
-#: path actually ran instead of silently falling back to numpy.
+#: dispatches).  Tests, perf_sim and chip_smoke.py assert on deltas to
+#: prove the jitted pipeline really ran.
 PIPELINE_CALLS = {"single": 0, "batched": 0}
 
 #: pair-list padding buckets (docs/performance.md).  Plan-reused phases
 #: only redraw the ~bg_flows_per_phase background rows, so their pair
 #: tail is padded to a small bucket; planless phases redraw everything
 #: and get a coarse bucket.  Bigger buckets = fewer distinct compiled
-#: shapes at the cost of a few zero-weight pairs per segment sum.
-_PAIR_BUCKET_PLAN = 256
+#: shapes at the cost of a few zero-weight pairs per segment sum.  The
+#: default 16 background flows x 6 candidates x 8 hops make at most 768
+#: pairs (400-540 in practice, straddling 512), so one 1024 bucket keeps
+#: every plan-reused phase on one executable.
+_PAIR_BUCKET_PLAN = 1024
 _PAIR_BUCKET_FULL = 4096
 
 #: block width of the sorted-head prefix sum.  The pinned sorted pair
 #: list is padded to a multiple of this (zero-mask entries on the last
 #: link), so the blocked cumsum needs no remainder handling.
 _CUMSUM_BLOCK = 1024
+
+
+def kernel_mode(params) -> tuple:
+    """(use_kernel, interpret) statics of the pipeline's segment sum.
+
+    The Pallas kernel runs compiled on a TPU.  Interpret mode happens
+    only where ``pallas_kernel="on"`` forces the kernel off the chip —
+    the parity-testing path."""
+    use_kernel = resolve_pallas_kernel(params.pallas_kernel)
+    return use_kernel, use_kernel and not on_tpu()
 
 
 def _padded_len(n: int, bucket: int) -> int:
@@ -102,7 +107,7 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
                     qwait_fraction, stall_gain, nic_latency_ns,
                     hop_latency_ns, *, n_spray: int, n_links: int,
                     use_kernel: bool, interpret: bool, p_sorted: int):
-    """One phase: score -> spray -> fori_loop feedback -> observables.
+    """One phase: score -> spray -> lax.scan feedback -> observables.
 
     Pure in its arguments; statics select the segment-sum implementation
     (Pallas vs jax.ops.segment_sum) and fix loop count / bin count.
@@ -211,9 +216,8 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     return w, rho, load_q, lat_us, s_flit
 
 
-#: positional index of cand_mask / gnoise in _phase_pipeline's signature
+#: positional index of cand_mask in _phase_pipeline's signature
 _MASK_ARG = 4
-_GNOISE_ARG = 13
 _N_ARGS = 29
 
 
@@ -224,8 +228,7 @@ def _jitted_pipeline(n_spray: int, n_links: int, use_kernel: bool,
     """Compiled pipeline per (statics, batched, mask-presence) combo.
 
     ``batched`` wraps the core in ``jax.vmap`` over a stacked leading
-    phase axis — scalars ride along as [B] vectors.  The Gumbel noise
-    block (the largest per-phase transfer) is donated.
+    phase axis — scalars ride along as [B] vectors.
     """
     core = functools.partial(_phase_pipeline, n_spray=n_spray,
                              n_links=n_links, use_kernel=use_kernel,
@@ -236,7 +239,7 @@ def _jitted_pipeline(n_spray: int, n_links: int, use_kernel: bool,
         if not has_mask:
             axes[_MASK_ARG] = None      # cand_mask=None: empty pytree
         fn = jax.vmap(core, in_axes=tuple(axes))
-    return jit_compiled(fn, donate_argnums=(_GNOISE_ARG,))
+    return jax.jit(fn)
 
 
 # ------------------------------------------------------- input preparation
@@ -304,7 +307,7 @@ def _tail_writer(n_app: int, p_head: int):
         pairs = tuple(b.at[p_head:].set(t)
                       for b, t in zip(bufs[4:], tails[4:]))
         return rows + pairs
-    return jit_compiled(write, donate_argnums=(0,))
+    return jax.jit(write, donate_argnums=(0,))
 
 
 def _pad_pairs(links: np.ndarray, fc: np.ndarray, pad_to: int):
@@ -409,19 +412,17 @@ def _prepare_inputs(sim, ctx: dict):
         jnp.float32(tp.nic_latency_ns), jnp.float32(tp.hop_latency_ns),
     )
     statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links),
-               resolve_pallas_kernel(p.pallas_kernel), not on_tpu(),
-               p_sorted)
+               *kernel_mode(p), p_sorted)
     return inputs, statics
 
 
 def batch_signature(sim, ctx: dict) -> tuple:
     """Hashable key: phases with equal keys (shapes + statics + mask
     presence) can share one vmapped dispatch."""
-    p = sim.params
     plan = ctx["plan"]
     return (int(sim.topo.n_links), int(ctx["gnoise"].shape[0]),
-            resolve_pallas_kernel(p.pallas_kernel), not on_tpu(),
-            tuple(ctx["safe"].shape), padded_pair_len(ctx),
+            *kernel_mode(sim.params), tuple(ctx["safe"].shape),
+            padded_pair_len(ctx),
             0 if plan is None else _padded_len(plan.pair_links.shape[0],
                                                _CUMSUM_BLOCK),
             ctx["cand_mask"] is not None)

@@ -37,7 +37,7 @@ Fast path (PR 3, docs/performance.md): the hot loop is vectorized —
     ``sim.plan_for(...)`` / ``run_phase(..., plan=...)``;
   * ``SimParams.backend = "jax"`` routes the score->spray->fixed-point->
     observables pipeline through one jitted JAX function (with a Pallas
-    segment-sum kernel on TPU), falling back to NumPy when unavailable.
+    segment-sum kernel on TPU); it raises when jax cannot be used.
 
 Seed-for-seed the NumPy fast path replays the pre-refactor simulator
 (`repro.dragonfly.reference`): bit-identical with
@@ -120,8 +120,9 @@ class SimParams:
     host_noise_sigma: float = 0.25     # lognormal sigma of host-side jitter
     nic_clock_ghz: float = 1.0
     #: compute backend for the phase kernel: "numpy" (default, seed-exact)
-    #: or "jax" (device-resident jitted pipeline; falls back to numpy
-    #: with a warning when jax is unusable).  docs/performance.md.
+    #: or "jax" (device-resident jitted pipeline; the simulator refuses
+    #: to build when jax or Pallas cannot be imported).
+    #: docs/performance.md.
     backend: str = "numpy"
     #: Pallas segment-sum inside the jax pipeline: "auto" uses it on TPU
     #: only (interpret-mode Pallas loses badly to jax.ops.segment_sum on
@@ -335,6 +336,13 @@ class DragonflySimulator:
             raise ValueError(
                 f"unknown pallas_kernel {params.pallas_kernel!r}; "
                 f"expected 'auto', 'on' or 'off'")
+        if params.backend == "jax":
+            # fail here, never mid-phase and never onto numpy
+            try:
+                import repro.dragonfly.jax_backend  # noqa: F401
+            except ImportError as e:
+                raise RuntimeError("SimParams(backend='jax') needs jax "
+                                   f"with Pallas: {e}") from e
         # topo=None resolves params.topology ("aries", "dragonfly:p=2,...",
         # any registered family spec) through make_topology
         self.topo = topo = make_topology(topo if topo is not None
@@ -810,14 +818,9 @@ class DragonflySimulator:
             t_rows = np.concatenate(
                 [t_rows,
                  np.full(n_bg, max(bg_policy.spray_temperature_s, 1e-12))])
-        # backend for THIS phase's kernel: the jax path consumes the
-        # fault cand_mask and notification penalties in-graph, so it no
-        # longer falls back to numpy on faulted/notified phases
-        backend = "numpy"
-        if p.backend == "jax":
-            from repro.compat.runtime import resolve_backend
-            if resolve_backend(p.backend) == "jax":
-                backend = "jax"
+        # the jax kernel consumes the fault cand_mask and notification
+        # penalties in-graph: every phase of a jax simulator runs there
+        backend = p.backend
         score0 = size_inst = nic_load = None
         if backend == "numpy":
             # host score base — skipped on the jax path, where the same
@@ -869,23 +872,7 @@ class DragonflySimulator:
                                        **self._numpy_kernel_kwargs(ctx))
 
     def _numpy_kernel_kwargs(self, ctx: dict) -> dict:
-        """Kwargs for `_fixed_point_numpy` from a phase context.
-
-        A ctx prepared for the jax kernel skips the host score base;
-        compute it on demand here (values identical to the eager numpy
-        path) so such a phase can still be demoted to numpy."""
-        if ctx["score0"] is None:
-            ctx["size_inst"] = np.minimum(
-                ctx["size_all"], ctx["cap_window"][ctx["nic_ids"]])
-            base = (ctx["est_queue_s"][ctx["safe"]]
-                    * ctx["valid"]).sum(axis=-1) \
-                + ctx["hl_rows"][:, None] * ctx["hops"]
-            ctx["score0"] = apply_bias(base, ctx["is_nonmin"],
-                                       ctx["bias_rows"], ctx["posinf"],
-                                       ctx["neginf"])
-            ctx["nic_load"] = np.bincount(
-                ctx["nic_ids"], weights=ctx["size_inst"],
-                minlength=self.topo.n_links)
+        """Kwargs for `_fixed_point_numpy` from a numpy phase context."""
         return dict(
             score0=ctx["score0"], safe=ctx["safe"], valid=ctx["valid"],
             hops=ctx["hops"], est_queue_s=ctx["est_queue_s"],

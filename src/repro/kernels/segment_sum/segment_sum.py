@@ -16,6 +16,12 @@ one-hot reduction:
 
 Out-of-range ids (the padding the wrapper adds to reach a block
 multiple) match no segment and vanish.
+
+Block widths: XLA tiles a 1-D f32/int32 array of 1024 or more elements
+as T(1024) on a TPU, and Mosaic refuses a 1-D block whose own tiling
+differs from the operand's.  Blocks are therefore 1024 wide, or the
+whole (shorter) axis; tests/test_tpu_compile.py compiles the engine's
+real widths for a described v5e.
 """
 
 from __future__ import annotations
@@ -46,9 +52,37 @@ def _segment_sum_kernel(ids_ref, val_ref, o_ref, *, block_segs: int):
 @functools.partial(jax.jit, static_argnames=("num_segments", "block_pairs",
                                              "block_segs", "interpret"))
 def segment_sum_pallas(values, segment_ids, num_segments: int, *,
-                       block_pairs: int = 1024, block_segs: int = 512,
+                       block_pairs: int = 1024, block_segs: int = 1024,
                        interpret: bool = False):
-    """values: [n] float; segment_ids: [n] int -> [num_segments] float32."""
+    """values: [n] float; segment_ids: [n] int -> [num_segments] float32.
+
+    Under ``jax.vmap`` the lanes go through the kernel one after another
+    (``lax.map``): batching the ``pallas_call`` itself would give each
+    1-D block a squeezed batch dim, and Mosaic requires the last two
+    block dims to tile by (8, 128) or span the array."""
+    return _lane_mapped(num_segments, block_pairs, block_segs,
+                        interpret)(values, segment_ids)
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_mapped(num_segments: int, block_pairs: int, block_segs: int,
+                 interpret: bool):
+    one = functools.partial(_segment_sum_1d, num_segments=num_segments,
+                            block_pairs=block_pairs, block_segs=block_segs,
+                            interpret=interpret)
+    fn = jax.custom_batching.custom_vmap(one)
+
+    @fn.def_vmap
+    def _lanes(axis_size, in_batched, values, segment_ids):
+        args = tuple(x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
+                     for x, b in zip((values, segment_ids), in_batched))
+        return jax.lax.map(lambda a: one(*a), args), True
+
+    return fn
+
+
+def _segment_sum_1d(values, segment_ids, *, num_segments: int,
+                    block_pairs: int, block_segs: int, interpret: bool):
     n = values.shape[0]
     bp = max(1, min(block_pairs, n))
     bs = max(1, min(block_segs, num_segments))

@@ -23,7 +23,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.analysis.hlo_parse import parse_hlo
 from repro.analysis.roofline import model_flops_estimate, roofline_terms
 from repro.configs import (SHAPES, ShapeNotSupported, get_config,
@@ -80,7 +79,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     in_shard = input_specs_sharding(specs, cfg, mesh, policy)
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             from repro.train.train_step import auto_microbatch
             dp = 1
@@ -143,7 +142,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
     costs = parse_hlo(txt)
     mesh_shape = tuple(mesh_override[0]) if mesh_override else (

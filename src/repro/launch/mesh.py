@@ -2,8 +2,8 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS first).  Mesh
-construction goes through repro.compat, which applies Auto axis_types
-on jax>=0.7 and omits them on 0.4.x (see docs/compat.md)."""
+construction goes through repro.compat.make_mesh, which gives every
+axis Auto sharding (see docs/compat.md)."""
 
 from __future__ import annotations
 

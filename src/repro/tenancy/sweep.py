@@ -21,13 +21,6 @@ from repro.tenancy.engine import (InterferenceEngine, arm_label,
 from repro.tenancy.spec import TenancyMix
 
 
-def _auto_lockstep(params: SimParams | None) -> bool:
-    if params is None or params.backend != "jax":
-        return False
-    from repro.compat.runtime import resolve_backend
-    return resolve_backend("jax") == "jax"
-
-
 def sweep(topo: Topology | str | None, mixes: Sequence[TenancyMix],
           arms: Mapping, *, params: SimParams | None = None,
           rounds: int = 4, seed: int = 0,
@@ -44,13 +37,13 @@ def sweep(topo: Topology | str | None, mixes: Sequence[TenancyMix],
     lockstep: drive each (mix, placement) column's arm cells
     round-for-round through one batched phase dispatch
     (`run_mixes_lockstep`) instead of cell-after-cell.  Default None
-    auto-enables it when the params ask for a usable jax backend, where
+    auto-enables it when the params ask for the jax backend, where
     the column becomes a single vmapped kernel call per round; records
     are identical either way because every cell keeps its own simulator
     and RNG stream.
     """
     if lockstep is None:
-        lockstep = _auto_lockstep(params)
+        lockstep = params is not None and params.backend == "jax"
     records = []
     for mix in mixes:
         for place in placements:

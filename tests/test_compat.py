@@ -1,217 +1,55 @@
-"""repro.compat — version detection + shim dispatch on BOTH jax branches.
+"""repro.compat — the helpers the repo keeps over the installed jax.
 
-Two matrices:
-
-  * the real installed jax (0.4.37 in the container): the legacy
-    fallbacks must actually work — build meshes, activate them, run a
-    shard_map collective;
-  * a monkeypatched jax>=0.7 surface: the shims must route to the
-    modern APIs with the translated kwargs (axis_types, check_vma),
-    proving the same call sites stay correct when the container's jax
-    is upgraded, without needing that jax installed.
-
-Dispatch is read from `repro.compat.version.HAS_*` at call time, which
-is what makes the monkeypatched matrix possible.
+  * `make_mesh` gives every axis AxisType.Auto, where `jax.make_mesh`
+    defaults to Explicit; `abstract_axis_sizes` reads the active mesh;
+  * `on_tpu` / `resolve_pallas_kernel` pick the segment-sum of the jax
+    phase engine;
+  * `enable_compile_cache` places JAX's persistent compilation cache:
+    the environment variable wins, else one fixed in-checkout path.
 """
 
-import contextlib
-import types
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
-from repro.compat import version as compat_version
+from repro.compat import runtime
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 # --------------------------------------------------------------------------
-# Version parsing / guard.
-# --------------------------------------------------------------------------
-def test_parse_version():
-    assert compat.parse_version("0.4.37") == (0, 4, 37)
-    assert compat.parse_version("0.7.0.dev20250101") == (0, 7, 0)
-    assert compat.parse_version("0.7") == (0, 7, 0)
-    assert compat.parse_version("1.2rc1") == (1, 2, 0)
-
-
-def test_jax_version_at_least_matches_installed():
-    assert compat.JAX_VERSION == compat.parse_version(jax.__version__)
-    assert compat.jax_version_at_least("0.4")
-    assert compat.jax_version_at_least(*compat.JAX_VERSION)
-    assert not compat.jax_version_at_least("99.0")
-    # string and int spellings agree
-    assert compat.jax_version_at_least("0.7") == \
-        compat.jax_version_at_least(0, 7)
-
-
-def test_describe_reports_flags():
-    d = compat.describe()
-    assert d["jax"] == jax.__version__
-    for key in ("set_mesh", "axis_type", "get_abstract_mesh",
-                "toplevel_shard_map"):
-        assert isinstance(d[key], bool)
-
-
-# --------------------------------------------------------------------------
-# Real-jax branch (whatever is installed; 0.4.37 in the container).
+# Meshes on the installed jax.
 # --------------------------------------------------------------------------
 def test_make_mesh_and_set_mesh_roundtrip():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert mesh.axis_names == ("data", "model")
     assert compat.abstract_axis_sizes() == {}          # outside set_mesh
-    with compat.set_mesh(mesh) as active:
-        assert active is mesh
+    with jax.set_mesh(mesh):
         assert compat.abstract_axis_sizes() == {"data": 1, "model": 1}
-        am = compat.get_abstract_mesh()
+        am = jax.sharding.get_abstract_mesh()
         assert tuple(am.axis_names) == ("data", "model")
     assert compat.abstract_axis_sizes() == {}
 
 
-def test_axis_types_matches_capability():
-    types_ = compat.axis_types(3)
-    if compat_version.HAS_AXIS_TYPE:
-        assert len(types_) == 3
-    else:
-        assert types_ is None
+def test_make_mesh_passes_auto_axis_types():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    # the wrapper exists because jax's own default differs
+    assert jax.make_mesh((1, 1), ("data", "model")).axis_types \
+        != mesh.axis_types
 
 
 def test_shard_map_runs_collective():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
-    f = compat.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
-                         in_specs=P(), out_specs=P(), check_vma=False)
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     np.testing.assert_allclose(np.asarray(f(jnp.ones((4,)))), 1.0)
-
-
-# --------------------------------------------------------------------------
-# Mocked jax>=0.7 branch: dispatch + kwarg translation.
-# --------------------------------------------------------------------------
-def test_set_mesh_routes_to_modern_api(monkeypatch):
-    entered = []
-
-    @contextlib.contextmanager
-    def fake_set_mesh(mesh):
-        entered.append(mesh)
-        yield mesh
-
-    monkeypatch.setattr(jax, "set_mesh", fake_set_mesh, raising=False)
-    monkeypatch.setattr(compat_version, "HAS_SET_MESH", True)
-    sentinel = object()
-    with compat.set_mesh(sentinel) as m:
-        assert m is sentinel
-    assert entered == [sentinel]
-
-
-def test_make_mesh_passes_auto_axis_types(monkeypatch):
-    seen = {}
-
-    def fake_make_mesh(shapes, names, **kw):
-        seen["args"] = (shapes, names, kw)
-        return "mesh"
-
-    monkeypatch.setattr(jax.sharding, "AxisType",
-                        types.SimpleNamespace(Auto="AUTO"), raising=False)
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    monkeypatch.setattr(compat_version, "HAS_AXIS_TYPE", True)
-    assert compat.make_mesh((2, 2), ("data", "model")) == "mesh"
-    shapes, names, kw = seen["args"]
-    assert shapes == (2, 2) and names == ("data", "model")
-    assert kw["axis_types"] == ("AUTO", "AUTO")
-
-
-def test_get_abstract_mesh_routes_to_modern_api(monkeypatch):
-    fake = types.SimpleNamespace(axis_names=("data", "model"),
-                                 shape={"data": 4, "model": 2})
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: fake,
-                        raising=False)
-    monkeypatch.setattr(compat_version, "HAS_GET_ABSTRACT_MESH", True)
-    assert compat.get_abstract_mesh() is fake
-    assert compat.abstract_axis_sizes() == {"data": 4, "model": 2}
-
-
-def test_shard_map_modern_branch_uses_check_vma(monkeypatch):
-    seen = {}
-
-    def fake_shard_map(f, *, mesh, in_specs, out_specs, check_vma):
-        seen.update(mesh=mesh, check_vma=check_vma)
-        return "modern"
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    monkeypatch.setattr(compat_version, "HAS_TOPLEVEL_SHARD_MAP", True)
-    out = compat.shard_map(lambda x: x, mesh="m", in_specs=P(),
-                           out_specs=P(), check_vma=False)
-    assert out == "modern"
-    assert seen == {"mesh": "m", "check_vma": False}
-
-
-def test_shard_map_legacy_branch_translates_to_check_rep(monkeypatch):
-    import jax.experimental.shard_map as esm
-    seen = {}
-
-    def fake_shard_map(f, *, mesh, in_specs, out_specs, check_rep):
-        seen.update(mesh=mesh, check_rep=check_rep)
-        return "legacy"
-
-    monkeypatch.setattr(esm, "shard_map", fake_shard_map)
-    monkeypatch.setattr(compat_version, "HAS_TOPLEVEL_SHARD_MAP", False)
-    out = compat.shard_map(lambda x: x, mesh="m", in_specs=P(),
-                           out_specs=P(), check_vma=False)
-    assert out == "legacy"
-    assert seen == {"mesh": "m", "check_rep": False}
-
-
-# --------------------------------------------------------------------------
-# cost_analysis drift (list-of-dicts on 0.4.x, dict on >=0.7).
-# --------------------------------------------------------------------------
-@pytest.mark.parametrize("raw,expected", [
-    ([{"flops": 7.0}], {"flops": 7.0}),        # 0.4.x list shape
-    ({"flops": 7.0}, {"flops": 7.0}),          # >=0.7 dict shape
-    ([], {}),
-    (None, {}),
-])
-def test_cost_analysis_normalizes_both_shapes(raw, expected):
-    compiled = types.SimpleNamespace(cost_analysis=lambda: raw)
-    assert compat.cost_analysis(compiled) == expected
-
-
-# --------------------------------------------------------------------------
-# jit_compiled donation drift (donate_argnums unsupported on ancient jit
-# signatures -> silently degrade to a plain jit).
-# --------------------------------------------------------------------------
-def test_jit_compiled_with_donation_runs():
-    fn = compat.jit_compiled(lambda x: x * 2.0, donate_argnums=(0,))
-    out = fn(jnp.ones(8, jnp.float32))
-    np.testing.assert_allclose(np.asarray(out), 2.0)
-
-
-def test_jit_compiled_degrades_when_donation_unsupported(monkeypatch):
-    calls = []
-
-    def fake_jit(fun, **kw):
-        calls.append(dict(kw))
-        if "donate_argnums" in kw:      # pre-donation jit signature
-            raise TypeError("unexpected keyword argument 'donate_argnums'")
-        return fun
-    monkeypatch.setattr(jax, "jit", fake_jit)
-    fn = compat.jit_compiled(lambda x: x + 1, donate_argnums=(0,),
-                             static_argnames=("n",))
-    assert fn(1) == 2                   # plain-jit fallback still runs
-    assert "donate_argnums" in calls[0]          # tried the modern path
-    assert "donate_argnums" not in calls[-1]     # retried without
-    assert calls[-1]["static_argnames"] == ("n",)
-
-
-def test_jit_compiled_without_donation_skips_probe(monkeypatch):
-    calls = []
-
-    def fake_jit(fun, **kw):
-        calls.append(dict(kw))
-        return fun
-    monkeypatch.setattr(jax, "jit", fake_jit)
-    compat.jit_compiled(lambda x: x)
-    assert calls == [{}]
 
 
 # --------------------------------------------------------------------------
@@ -221,17 +59,8 @@ def test_on_tpu_matches_default_backend():
     assert compat.on_tpu() == (jax.default_backend() == "tpu")
 
 
-def test_on_tpu_false_when_jax_unusable(monkeypatch):
-    import repro.compat.runtime as rt
-    monkeypatch.setattr(rt, "_JAX_OK", False)
-    assert compat.on_tpu() is False
-
-
 def test_resolve_pallas_kernel_auto_follows_tpu(monkeypatch):
-    import repro.compat.runtime as rt
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(rt, "_JAX_OK", True)
-    monkeypatch.setattr(rt, "_PALLAS_OK", True)
     assert compat.resolve_pallas_kernel("auto") is True
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert compat.resolve_pallas_kernel("auto") is False
@@ -243,3 +72,35 @@ def test_resolve_pallas_kernel_forced_ignores_hardware(monkeypatch):
     assert compat.resolve_pallas_kernel("off") is False
     with pytest.raises(ValueError):
         compat.resolve_pallas_kernel("banana")
+
+
+# --------------------------------------------------------------------------
+# Compile-cache placement (jax.config.update is recorded, never applied:
+# tests leave the persistent cache off).
+# --------------------------------------------------------------------------
+@pytest.fixture
+def config_updates(monkeypatch):
+    seen = []
+    monkeypatch.setattr(runtime.jax.config, "update",
+                        lambda name, value: seen.append((name, value)))
+    return seen
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path, config_updates):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compat.enable_compile_cache() == str(tmp_path)
+    assert config_updates == []         # JAX reads the variable itself
+
+
+def test_compile_cache_falls_back_to_fixed_repo_dir(monkeypatch,
+                                                    config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    where = compat.enable_compile_cache()
+    assert where == str(REPO / ".jax_cache")
+    assert config_updates == [("jax_compilation_cache_dir", where)]
+    assert compat.enable_compile_cache() == where     # never moves
+
+
+def test_compile_cache_dir_is_ignored_by_git():
+    assert runtime.COMPILE_CACHE_DIR == REPO / ".jax_cache"
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().splitlines()

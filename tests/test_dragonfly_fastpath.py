@@ -5,7 +5,7 @@ differential (threshold=inf replays the channel-free simulator
 bit-for-bit across the whole topology family)."""
 
 import hashlib
-import warnings
+import sys
 
 import numpy as np
 import pytest
@@ -216,17 +216,11 @@ def test_bg_flows_unchanged_when_disjoint():
 
 
 # --------------------------------------------------------------------------
-# jax backend: tolerance matrix + clean fallback.
+# jax backend: tolerance matrix; no fallback onto numpy.
 # --------------------------------------------------------------------------
 JAX_RTOL = 2e-2   # float32 pipeline vs float64 numpy (docs/performance.md)
 
 
-def _jax_ok():
-    from repro.compat.runtime import resolve_backend
-    return resolve_backend("jax") == "jax"
-
-
-@pytest.mark.skipif(not _jax_ok(), reason="jax unavailable")
 @pytest.mark.parametrize("mode", list(RoutingMode))
 def test_jax_backend_matches_numpy_within_tolerance(mode):
     src, dst, size = _flows(seed=3, n=250)
@@ -244,7 +238,6 @@ def test_jax_backend_matches_numpy_within_tolerance(mode):
                                                rel=JAX_RTOL, abs=1e-4)
 
 
-@pytest.mark.skipif(not _jax_ok(), reason="jax unavailable")
 def test_jax_backend_matches_numpy_mixed_modes():
     src, dst, size = _flows(seed=3, n=250)
     pool = [RoutingMode.ADAPTIVE_0, RoutingMode.ADAPTIVE_2,
@@ -259,24 +252,20 @@ def test_jax_backend_matches_numpy_mixed_modes():
     np.testing.assert_allclose(rj.t_us, rn.t_us, rtol=JAX_RTOL)
 
 
-def test_jax_backend_falls_back_cleanly(monkeypatch):
-    """With jax reported unusable, backend='jax' degrades to numpy and
-    reproduces its bit-exact results after a single warning."""
-    import repro.compat.runtime as rt
+@pytest.mark.parametrize("missing", ["jax", "jax.experimental.pallas"])
+def test_jax_backend_raises_when_jax_unusable(monkeypatch, missing):
+    """With jax or Pallas unimportable, backend='jax' refuses to build
+    the simulator: it never continues on numpy."""
+    import jax.experimental
 
-    monkeypatch.setattr(rt, "_JAX_OK", False)
-    monkeypatch.setattr(rt, "_WARNED_FALLBACK", False)
-    src, dst, size = _flows(seed=1, n=100)
-    sim_j = DragonflySimulator(TOPO, SimParams(seed=1, backend="jax"))
-    sim_n = DragonflySimulator(TOPO, SimParams(seed=1))
-    pol = RoutingPolicy(RoutingMode.ADAPTIVE_0)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        rj = sim_j.run_phase(src, dst, size, pol)
-        sim_j.run_phase(src, dst, size, pol)
-    assert any("falling back" in str(w.message) for w in caught)
-    rn = sim_n.run_phase(src, dst, size, pol)
-    _assert_flowresult_equal(rj, rn)
+    for mod in ("repro.dragonfly.jax_backend",
+                "repro.kernels.segment_sum.segment_sum"):
+        monkeypatch.delitem(sys.modules, mod)
+    monkeypatch.delattr(jax.experimental, "pallas", raising=False)
+    monkeypatch.setitem(sys.modules, missing, None)
+    with pytest.raises(RuntimeError, match="needs jax with Pallas"):
+        DragonflySimulator(TOPO, SimParams(backend="jax"))
+    DragonflySimulator(TOPO, SimParams())   # numpy needs neither
 
 
 def test_unknown_backend_rejected():

@@ -33,14 +33,6 @@ TOPO = DragonflyTopology(TopologyParams(n_groups=4, chassis_per_group=2,
                                         blades_per_chassis=4))
 
 
-def _jax_ok():
-    from repro.compat.runtime import resolve_backend
-    return resolve_backend("jax") == "jax"
-
-
-requires_jax = pytest.mark.skipif(not _jax_ok(), reason="jax unavailable")
-
-
 def _flows(topo, seed=42, n=400):
     rng = np.random.default_rng(seed)
     n_nodes = topo.n_nodes
@@ -68,7 +60,6 @@ def _dispatches():
 # the jax pipeline must actually DISPATCH on the masked/notified phases
 # (they used to silently fall back to numpy).
 # --------------------------------------------------------------------------
-@requires_jax
 @pytest.mark.parametrize("name", ["aries", "dragonfly", "dragonfly_plus"])
 @pytest.mark.parametrize("scenario", ["healthy", "faulted", "notifying"])
 def test_jax_parity_topology_family(name, scenario):
@@ -95,7 +86,6 @@ def test_jax_parity_topology_family(name, scenario):
         assert sims["jax"].notify_epoch() == sims["numpy"].notify_epoch()
 
 
-@requires_jax
 def test_jax_faulted_phase_runs_on_device_with_plan():
     """Fault cand_mask phases ride the plan-pinned device path too, and
     stranded flows (all candidates dead) agree with numpy."""
@@ -119,7 +109,6 @@ def test_jax_faulted_phase_runs_on_device_with_plan():
 # --------------------------------------------------------------------------
 # Device/queue state across reset_queues() and epoch bumps.
 # --------------------------------------------------------------------------
-@requires_jax
 def test_jax_state_survives_reset_and_epoch_bumps():
     """One interleaved life: phases -> reset_queues -> phases -> fault
     epoch bump -> phases.  The jax sim must track the numpy oracle
@@ -161,7 +150,6 @@ def test_jax_state_survives_reset_and_epoch_bumps():
 # --------------------------------------------------------------------------
 # pallas_kernel knob.
 # --------------------------------------------------------------------------
-@requires_jax
 def test_pallas_kernel_on_agrees_with_off():
     """force-"on" (interpret mode off-TPU) replays the "off" scatter
     path within the pinned tolerance — the kernel parity contract."""
@@ -207,8 +195,6 @@ def _batch_calls(backend, n_sims=3, seed0=20):
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_run_phase_batch_matches_sequential(backend):
-    if backend == "jax" and not _jax_ok():
-        pytest.skip("jax unavailable")
     batched = [run_phase_batch([(sim, dict(kw))
                                 for sim, kw in _batch_calls(backend)])
                for _ in range(1)][0]
@@ -220,7 +206,6 @@ def test_run_phase_batch_matches_sequential(backend):
         assert np.array_equal(rb.flits, rs.flits)
 
 
-@requires_jax
 def test_run_phase_batch_uses_one_vmapped_dispatch():
     from repro.dragonfly.jax_backend import PIPELINE_CALLS
     before = dict(PIPELINE_CALLS)
@@ -246,8 +231,6 @@ def _sweep(backend, lockstep):
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_sweep_lockstep_matches_sequential(backend):
-    if backend == "jax" and not _jax_ok():
-        pytest.skip("jax unavailable")
     seq = _sweep(backend, lockstep=False)
     lck = _sweep(backend, lockstep=True)
     assert len(seq) == len(lck) == 2
@@ -259,7 +242,6 @@ def test_sweep_lockstep_matches_sequential(backend):
                 assert a[key] == b[key]
 
 
-@requires_jax
 def test_sweep_lockstep_batches_the_column():
     from repro.dragonfly.jax_backend import PIPELINE_CALLS
     before = PIPELINE_CALLS["batched"]

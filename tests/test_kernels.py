@@ -105,6 +105,7 @@ def test_rmsnorm_kernel_matches_ref(shape, dtype):
     (257, 64, 256, 64),          # ragged pair tail
     (64, 1000, 64, 256),         # more segments than pairs
     (5, 3, 1024, 512),           # tiny, single block
+    (3000, 2500, 1024, 1024),    # the default (chip-tiled) blocks
 ])
 def test_segment_sum_kernel_matches_ref(n, segs, bp, bs):
     from repro.kernels.segment_sum import segment_sum_ref
@@ -118,6 +119,33 @@ def test_segment_sum_kernel_matches_ref(n, segs, bp, bs):
     assert out.shape == (segs,)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_segment_sum_kernel_vmaps_lane_by_lane():
+    """Under vmap (the lockstep batch) each lane is its own segment sum,
+    batched and unbatched ids alike."""
+    import jax
+
+    from repro.kernels.segment_sum import segment_sum_ref
+    from repro.kernels.segment_sum.segment_sum import segment_sum_pallas
+
+    ids = jnp.asarray(RNG.integers(0, 300, size=(3, 700)), jnp.int32)
+    vals = jnp.asarray(RNG.random((3, 700)), jnp.float32)
+
+    def kern(v, i):
+        return segment_sum_pallas(v, i, 300, interpret=True)
+
+    def ref(v, i):
+        return segment_sum_ref(v, i, 300)
+
+    np.testing.assert_allclose(np.asarray(jax.vmap(kern)(vals, ids)),
+                               np.asarray(jax.vmap(ref)(vals, ids)),
+                               rtol=1e-5, atol=1e-5)
+    shared = jax.vmap(kern, in_axes=(0, None))(vals, ids[0])
+    np.testing.assert_allclose(
+        np.asarray(shared),
+        np.asarray(jax.vmap(ref, in_axes=(0, None))(vals, ids[0])),
+        rtol=1e-5, atol=1e-5)
 
 
 def test_segment_sum_empty_and_untouched_segments():

@@ -40,7 +40,7 @@ def test_moe_ep_matches_ref_on_trivial_mesh():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 16, 32)),
                     jnp.float32)
     mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_ep = jax.jit(lambda p, x: moe_ep(p, x, cfg))(p, x)
     y_ref, aux_ref = moe_ep_ref(p, x, cfg)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
@@ -54,7 +54,7 @@ def test_moe_ep_grads_finite():
     x = jnp.asarray(np.random.default_rng(1).standard_normal((2, 8, 32)),
                     jnp.float32)
     mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(lambda p, x: moe_ep(p, x, cfg)[0].sum()))(p, x)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()

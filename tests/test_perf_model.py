@@ -67,8 +67,10 @@ def test_flit_threshold_is_the_eq2_crossover(l_a, l_b, s_a, s_b, size):
     thr = flit_threshold(l_a, s_a, l_b, s_b, p)
     tb = transmission_cycles_eq2(l_b, s_b, f, p)
     ta = transmission_cycles_eq2(l_a, s_a, f, p)
-    if math.isinf(thr):
-        # b dominates (never-worse) — Eq2 must agree
+    if thr == math.inf:
+        # b dominates (never-worse) — Eq2 must agree.  -inf is not this
+        # corner: a vanishing s_b - s_a with L_b > L_a overflows the
+        # crossover to below every flit count (b never wins)
         assert tb <= ta + 1e-6 * max(ta, 1.0)
     elif s_b > s_a:
         if f < thr:

@@ -81,7 +81,7 @@ def test_allreduce_schedules_agree():
         mesh = compat.make_mesh((2, 2, 4), ("pod", "data", "model"))
         x = np.random.default_rng(0).standard_normal((16, 8, 3)).astype(np.float32)
         def run(fn):
-            return compat.shard_map(fn, mesh=mesh,
+            return jax.shard_map(fn, mesh=mesh,
                                  in_specs=P(("pod", "data", "model")),
                                  out_specs=P(("pod", "data", "model")),
                                  check_vma=False)(x)
@@ -101,18 +101,18 @@ def test_alltoall_schedules_roundtrip():
         from repro.collectives import alltoall_direct, alltoall_hierarchical
         mesh = compat.make_mesh((2, 2, 4), ("pod", "data", "model"))
         y = np.arange(64*4, dtype=np.float32).reshape(64, 4)
-        da = compat.shard_map(lambda v: alltoall_direct(v, "model"), mesh=mesh,
+        da = jax.shard_map(lambda v: alltoall_direct(v, "model"), mesh=mesh,
                               in_specs=P(("pod", "data", "model")),
                               out_specs=P(("pod", "data", "model")),
                               check_vma=False)(y)
         # a2a is an involution on 2 axes of equal split: applying the
         # direct exchange twice restores the input
-        da2 = compat.shard_map(lambda v: alltoall_direct(alltoall_direct(v, "model"), "model"),
+        da2 = jax.shard_map(lambda v: alltoall_direct(alltoall_direct(v, "model"), "model"),
                                mesh=mesh, in_specs=P(("pod", "data", "model")),
                                out_specs=P(("pod", "data", "model")),
                                check_vma=False)(y)
         np.testing.assert_allclose(np.asarray(da2), y)
-        h = compat.shard_map(lambda v: alltoall_hierarchical(v, "pod", "data"),
+        h = jax.shard_map(lambda v: alltoall_hierarchical(v, "pod", "data"),
                              mesh=mesh, in_specs=P(("pod", "data", "model")),
                              out_specs=P(("pod", "data", "model")),
                              check_vma=False)(y)
