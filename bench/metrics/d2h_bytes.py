@@ -1,0 +1,26 @@
+"""Bytes of the pipeline's outputs fetched back to the host per
+dispatched phase, as the device held them:
+``jax_backend.TRANSFER["d2h_bytes"]`` over the jitted dispatches
+(``jax_backend.PIPELINE_CALLS``).
+
+The counters run for the whole run, warm-up included: the harness
+clears only the stage clock before the window.  Pinning a plan is not
+counted, and every dispatched phase of a cell takes the same path,
+moving the same copies (tests/test_stage_clock.py); its bytes follow
+the phase's size.  So the mean is the per-phase value, and in a cell
+whose unit runs several phases of different sizes it is their mean, as
+every unit runs all of them.  None where the program has no such
+counter or dispatched nothing."""
+
+LAYER = "host-device transfer"
+MOVES = "phase_s"
+COUNTER = "d2h_bytes"
+
+
+def read(obs):
+    from repro.dragonfly import jax_backend
+    counts = getattr(jax_backend, "TRANSFER", {})
+    calls = sum(jax_backend.PIPELINE_CALLS.values())
+    if COUNTER not in counts or not calls:
+        return None
+    return counts[COUNTER] / calls

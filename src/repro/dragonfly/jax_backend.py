@@ -67,6 +67,15 @@ from repro.kernels.segment_sum.segment_sum import segment_sum_pallas
 #: prove the jitted pipeline really ran.
 PIPELINE_CALLS = {"single": 0, "batched": 0}
 
+#: host<->device traffic of the pipeline, counted always (a few integer
+#: adds a phase).  ``h2d_copies``/``h2d_bytes``: host buffers handed to
+#: the device for a phase's inputs, with their bytes as handed (a
+#: float64 buffer converted on the device counts 8 B an element);
+#: ``d2h_bytes``: the outputs fetched back.  The one-off pinning of a
+#: plan's invariant tensors (`_device_plan`) is not counted, so a plan's
+#: first phase counts as every other.
+TRANSFER = {"h2d_copies": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+
 #: pair-list padding buckets (docs/performance.md).  Plan-reused phases
 #: only redraw the ~bg_flows_per_phase background rows, so their pair
 #: tail is padded to a small bucket; planless phases redraw everything
@@ -124,48 +133,54 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     the scatter layout its kernel is written for.
     """
     def seg_sum(vals, ids):
-        if use_kernel:
-            return segment_sum_pallas(vals, ids, n_links,
-                                      interpret=interpret)
-        return segment_sum_ref(vals, ids, n_links)
+        with jax.named_scope("segsum"):
+            if use_kernel:
+                return segment_sum_pallas(vals, ids, n_links,
+                                          interpret=interpret)
+            return segment_sum_ref(vals, ids, n_links)
 
     def pair_sum(vals):
         if use_kernel or not p_sorted:
             return seg_sum(vals, pair_links)
-        # blocked prefix sum over the sorted head: per-block cumsums
-        # vectorize across rows where XLA CPU's 1-D cumsum does not, and
-        # only the [n_links+1] boundary prefixes ever materialize.
-        nb = p_sorted // _CUMSUM_BLOCK
-        within = jnp.cumsum(vals[:p_sorted].reshape(nb, _CUMSUM_BLOCK),
-                            axis=1)
-        base = jnp.concatenate([jnp.zeros(1, vals.dtype),
-                                jnp.cumsum(within[:, -1])])
-        i, j = seg_off // _CUMSUM_BLOCK, seg_off % _CUMSUM_BLOCK
-        w_in = within[jnp.minimum(i, nb - 1), jnp.maximum(j - 1, 0)]
-        pref = base[i] + jnp.where(j > 0, w_in, 0.0)
-        out = pref[1:] - pref[:-1]
+        with jax.named_scope("segsum"):
+            # blocked prefix sum over the sorted head: per-block cumsums
+            # vectorize across rows where XLA CPU's 1-D cumsum does not,
+            # and only the [n_links+1] boundary prefixes materialize.
+            nb = p_sorted // _CUMSUM_BLOCK
+            within = jnp.cumsum(
+                vals[:p_sorted].reshape(nb, _CUMSUM_BLOCK), axis=1)
+            base = jnp.concatenate([jnp.zeros(1, vals.dtype),
+                                    jnp.cumsum(within[:, -1])])
+            i, j = seg_off // _CUMSUM_BLOCK, seg_off % _CUMSUM_BLOCK
+            w_in = within[jnp.minimum(i, nb - 1), jnp.maximum(j - 1, 0)]
+            pref = base[i] + jnp.where(j > 0, w_in, 0.0)
+            out = pref[1:] - pref[:-1]
         if vals.shape[0] > p_sorted:
             out = out + seg_sum(vals[p_sorted:], pair_links[p_sorted:])
         return out
 
     # loop-invariant score base, in-graph (the hoisted scorer of the
     # numpy fast path: estimate gather + hop latency + bias terms)
-    base = (est_queue_s[safe] * validf).sum(axis=-1) \
-        + hl_rows[:, None] * hops
-    score0 = base + jnp.where(is_nonmin[None, :], bias_rows[:, None], 0.0)
-    score0 = jnp.where(posinf[:, None] & is_nonmin[None, :], jnp.inf,
-                       score0)
-    score0 = jnp.where(neginf[:, None] & ~is_nonmin[None, :], jnp.inf,
-                       score0)
-    if cand_mask is not None:
-        # fault path: candidates crossing dead links spray exactly zero
-        # (all-False rows — stranded flows — spray nowhere)
-        score0 = jnp.where(cand_mask, score0, jnp.inf)
+    with jax.named_scope("score"):
+        base = (est_queue_s[safe] * validf).sum(axis=-1) \
+            + hl_rows[:, None] * hops
+        score0 = base + jnp.where(is_nonmin[None, :], bias_rows[:, None],
+                                  0.0)
+        score0 = jnp.where(posinf[:, None] & is_nonmin[None, :], jnp.inf,
+                           score0)
+        score0 = jnp.where(neginf[:, None] & ~is_nonmin[None, :], jnp.inf,
+                           score0)
+        if cand_mask is not None:
+            # fault path: candidates crossing dead links spray exactly
+            # zero (all-False rows — stranded flows — spray nowhere)
+            score0 = jnp.where(cand_mask, score0, jnp.inf)
 
     # a flow cannot inject more than its NIC moves in the window
-    size_inst = jnp.minimum(size_all, cap_window[nic_ids])
-    nic_load = seg_sum(size_inst, nic_ids)
+    with jax.named_scope("loads"):
+        size_inst = jnp.minimum(size_all, cap_window[nic_ids])
+        nic_load = seg_sum(size_inst, nic_ids)
 
+    @jax.named_scope("spray")
     def spray(score, g):
         s = score + g * noise_scale
         s = jnp.where(jnp.isfinite(s), s, jnp.inf)
@@ -176,6 +191,7 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
         tot = jnp.where(tot <= 0, 1.0, tot)
         return z / tot
 
+    @jax.named_scope("loads")
     def loads(w):
         vals = (size_inst[:, None] * w).reshape(-1)[pair_fc] * pair_mask
         return pair_sum(vals) + nic_load
@@ -193,26 +209,29 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     # scan (not fori_loop + dynamic_index): the per-iteration noise block
     # arrives as a scanned input, so XLA skips the in-loop gather-copy of
     # gnoise[it]; compile time still does not scale with n_spray
-    (w, load_i), _ = jax.lax.scan(body, (w0, loads(w0)), gnoise[1:])
+    with jax.named_scope("feedback"):
+        (w, load_i), _ = jax.lax.scan(body, (w0, loads(w0)), gnoise[1:])
     del n_spray                           # loop count lives in the shape
 
-    load_q = pair_sum((size_all[:, None] * w).reshape(-1)[pair_fc]
-                      * pair_mask)
-    rho = load_i / cap_window
+    with jax.named_scope("loads"):
+        load_q = pair_sum((size_all[:, None] * w).reshape(-1)[pair_fc]
+                          * pair_mask)
 
     # --- observables: per-flow (L_us, s) ------------------------------
-    rho_path = rho[safe] * validf                   # [n, ncand, hops]
-    excess = jnp.maximum(0.0, rho_path - rho_threshold)
-    qdelay_ns = queue_delay_ns * excess.sum(axis=-1)
-    qwait_ns = (link_queue_s[safe] * validf).sum(axis=-1) \
-        * qwait_fraction * 1e9
-    lat_ns_cand = 2.0 * nic_latency_ns + hops * hop_latency_ns \
-        + qdelay_ns + qwait_ns
-    lat_us = (lat_ns_cand * w).sum(axis=-1) / 1e3
-    rho_nic = rho[nic_ids]
-    rho_bneck = jnp.maximum(rho_path.max(axis=-1), rho_nic[:, None])
-    s_cand = stall_gain * jnp.maximum(0.0, rho_bneck - rho_threshold)
-    s_flit = (s_cand * w).sum(axis=-1)
+    with jax.named_scope("observables"):
+        rho = load_i / cap_window
+        rho_path = rho[safe] * validf                   # [n, ncand, hops]
+        excess = jnp.maximum(0.0, rho_path - rho_threshold)
+        qdelay_ns = queue_delay_ns * excess.sum(axis=-1)
+        qwait_ns = (link_queue_s[safe] * validf).sum(axis=-1) \
+            * qwait_fraction * 1e9
+        lat_ns_cand = 2.0 * nic_latency_ns + hops * hop_latency_ns \
+            + qdelay_ns + qwait_ns
+        lat_us = (lat_ns_cand * w).sum(axis=-1) / 1e3
+        rho_nic = rho[nic_ids]
+        rho_bneck = jnp.maximum(rho_path.max(axis=-1), rho_nic[:, None])
+        s_cand = stall_gain * jnp.maximum(0.0, rho_bneck - rho_threshold)
+        s_flit = (s_cand * w).sum(axis=-1)
     return w, rho, load_q, lat_us, s_flit
 
 
@@ -243,12 +262,21 @@ def _jitted_pipeline(n_spray: int, n_links: int, use_kernel: bool,
 
 
 # ------------------------------------------------------- input preparation
+def _put(a, dtype=None):
+    """Hand host buffer ``a`` to the device as ``dtype`` (its own dtype
+    when None), counted in `TRANSFER` as one of a phase's input copies."""
+    a = np.asarray(a)
+    TRANSFER["h2d_copies"] += 1
+    TRANSFER["h2d_bytes"] += a.nbytes
+    return jnp.asarray(a, dtype=dtype)
+
+
 def _f32(a):
-    return jnp.asarray(a, dtype=jnp.float32)
+    return _put(a, jnp.float32)
 
 
 def _i32(a):
-    return jnp.asarray(a, dtype=jnp.int32)
+    return _put(a, jnp.int32)
 
 
 def _device_plan(plan, n_links: int) -> dict:
@@ -266,7 +294,8 @@ def _device_plan(plan, n_links: int) -> dict:
     blocked cumsum-diff reduction needs sorted block-aligned segments,
     and scatter-based consumers are order-insensitive, so the reorder is
     transparent to the Pallas path.  The plan's own (host) arrays keep
-    original order: numpy-backend parity is untouched."""
+    original order: numpy-backend parity is untouched.  These one-off
+    uploads stay out of `TRANSFER`, which counts a phase's own."""
     dev = plan.device_bundle
     if dev is None:
         pl = np.asarray(plan.pair_links)
@@ -281,14 +310,14 @@ def _device_plan(plan, n_links: int) -> dict:
         off = np.zeros(n_links + 1, dtype=np.int64)
         np.cumsum(np.bincount(links, minlength=n_links), out=off[1:])
         dev = {
-            "safe": _i32(plan.safe),
-            "validf": _f32(plan.valid),
-            "hops": _f32(plan.hops),
-            "nic_ids": _i32(plan.nic_ids),
+            "safe": jnp.asarray(plan.safe, dtype=jnp.int32),
+            "validf": jnp.asarray(plan.valid, dtype=jnp.float32),
+            "hops": jnp.asarray(plan.hops, dtype=jnp.float32),
+            "nic_ids": jnp.asarray(plan.nic_ids, dtype=jnp.int32),
             "pair_links": jnp.asarray(links),
             "pair_fc": jnp.asarray(fc),
             "pair_mask": jnp.asarray(mask),
-            "seg_off": _i32(off),
+            "seg_off": jnp.asarray(off, dtype=jnp.int32),
             "p_sorted": p_pad,
         }
         plan.device_bundle = dev
@@ -323,7 +352,7 @@ def _pad_pairs(links: np.ndarray, fc: np.ndarray, pad_to: int):
     pf[:n] = fc
     pm = np.zeros(pad_to, dtype=np.float32)
     pm[:n] = 1.0
-    return jnp.asarray(pl), jnp.asarray(pf), jnp.asarray(pm)
+    return _put(pl), _put(pf), _put(pm)
 
 
 def padded_pair_len(ctx: dict) -> int:
@@ -397,19 +426,19 @@ def _prepare_inputs(sim, ctx: dict):
 
     cm = ctx["cand_mask"]
     inputs = (
-        safe, validf, hops, jnp.asarray(ctx["is_nonmin"]),
-        None if cm is None else jnp.asarray(cm),
+        safe, validf, hops, _put(ctx["is_nonmin"]),
+        None if cm is None else _put(cm),
         _f32(ctx["est_queue_s"]), _f32(sim.link_queue_s),
         _f32(ctx["hl_rows"]), _f32(ctx["bias_rows"]),
-        jnp.asarray(ctx["posinf"]), jnp.asarray(ctx["neginf"]),
+        _put(ctx["posinf"]), _put(ctx["neginf"]),
         _f32(ctx["t_rows"]), _f32(ctx["noise_scale"]),
-        jnp.asarray(np.asarray(ctx["gnoise"], dtype=np.float32)),
+        _put(np.asarray(ctx["gnoise"], dtype=np.float32)),
         _f32(ctx["size_all"]), _f32(ctx["cap_window"]), nic_ids,
         pair_links, pair_fc, pair_mask, seg_off,
-        jnp.float32(ctx["window_s"]), jnp.float32(p.feedback_rho0),
-        jnp.float32(p.rho_threshold), jnp.float32(p.queue_delay_ns),
-        jnp.float32(p.qwait_fraction), jnp.float32(p.stall_gain),
-        jnp.float32(tp.nic_latency_ns), jnp.float32(tp.hop_latency_ns),
+        _f32(ctx["window_s"]), _f32(p.feedback_rho0),
+        _f32(p.rho_threshold), _f32(p.queue_delay_ns),
+        _f32(p.qwait_fraction), _f32(p.stall_gain),
+        _f32(tp.nic_latency_ns), _f32(tp.hop_latency_ns),
     )
     statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links),
                *kernel_mode(p), p_sorted)
@@ -429,15 +458,39 @@ def batch_signature(sim, ctx: dict) -> tuple:
 
 
 # ------------------------------------------------------------ entry points
+def _dispatch(sim, phase: int, fn, inputs):
+    """``fn(*inputs)`` in the stage ``device_wait``, which under
+    ``profile_stages`` also waits for its outputs."""
+    with sim.stage("device_wait", phase):
+        out = fn(*inputs)
+        if sim.params.profile_stages:
+            jax.block_until_ready(out)
+    return out
+
+
+def _fetch(sim, phase: int, out) -> tuple:
+    """The outputs copied to host float64, in the stage ``fetch``."""
+    TRANSFER["d2h_bytes"] += sum(o.nbytes for o in out)
+    with sim.stage("fetch", phase):
+        return tuple(np.asarray(o, dtype=np.float64) for o in out)
+
+
 def fixed_point_jax(sim, ctx: dict):
     """One phase on device; float64 numpy outputs (kernel contract:
-    (w, rho, load_q, lat_us, s_flit), same as `_fixed_point_numpy`)."""
-    inputs, statics = _prepare_inputs(sim, ctx)
+    (w, rho, load_q, lat_us, s_flit), same as `_fixed_point_numpy`).
+
+    Its stages, inside the simulator's ``fixed_point``: ``transfer``
+    (every input handed to the device), ``device_wait`` (the dispatch
+    until the outputs are ready) and ``fetch`` (the outputs copied to
+    host float64)."""
+    phase = ctx["phase"]
+    with sim.stage("transfer", phase):
+        inputs, statics = _prepare_inputs(sim, ctx)
     fn = _jitted_pipeline(*statics, batched=False,
                           has_mask=ctx["cand_mask"] is not None)
-    out = fn(*inputs)
+    out = _dispatch(sim, phase, fn, inputs)
     PIPELINE_CALLS["single"] += 1
-    return tuple(np.asarray(o, dtype=np.float64) for o in out)
+    return _fetch(sim, phase, out)
 
 
 def fixed_point_jax_batch(batch):
@@ -447,18 +500,22 @@ def fixed_point_jax_batch(batch):
     groups).  Returns one kernel-output tuple per entry, batch order.
     Cells keep their own simulators/RNG streams — batching changes the
     dispatch, not the draws, so results match per-cell dispatch within
-    float32 reassociation noise."""
-    prepped = [_prepare_inputs(sim, ctx) for sim, ctx in batch]
-    statics = prepped[0][1]
-    has_mask = batch[0][1]["cand_mask"] is not None
-    stacked = []
-    for j, col in enumerate(zip(*(inp for inp, _ in prepped))):
-        if j == _MASK_ARG and not has_mask:
-            stacked.append(None)
-            continue
-        stacked.append(jnp.stack(col))
+    float32 reassociation noise.
+
+    The stages ``transfer``, ``device_wait`` and ``fetch`` are recorded
+    once per dispatch, on the batch's first simulator and in its phase."""
+    sim, phase = batch[0][0], batch[0][1]["phase"]
+    with sim.stage("transfer", phase):
+        prepped = [_prepare_inputs(s, ctx) for s, ctx in batch]
+        statics = prepped[0][1]
+        has_mask = batch[0][1]["cand_mask"] is not None
+        stacked = []
+        for j, col in enumerate(zip(*(inp for inp, _ in prepped))):
+            if j == _MASK_ARG and not has_mask:
+                stacked.append(None)
+                continue
+            stacked.append(jnp.stack(col))
     fn = _jitted_pipeline(*statics, batched=True, has_mask=has_mask)
-    outs = fn(*stacked)
+    outs = _fetch(sim, phase, _dispatch(sim, phase, fn, stacked))
     PIPELINE_CALLS["batched"] += 1
-    return [tuple(np.asarray(o[b], dtype=np.float64) for o in outs)
-            for b in range(len(batch))]
+    return [tuple(o[b] for o in outs) for b in range(len(batch))]

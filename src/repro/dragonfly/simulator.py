@@ -66,6 +66,69 @@ from repro.dragonfly.topology import (PAD, Allocation, DragonflyTopology,
 BACKENDS = ("numpy", "jax")
 
 
+class _Stage:
+    """One open stage of a phase under ``SimParams.profile_stages``.
+
+    Its host seconds, from its start to ``stop()``, add into
+    ``sim.stage_time_s[name]``; the same extent is the profiler span
+    ``df.<name>`` with the phase's index as its ``phase`` argument, on
+    the clock of the device trace when a capture runs.  The span encloses
+    the clock reads, so a span is never shorter than its reading.  A
+    second ``stop()`` does nothing, so a caller may stop whatever stage
+    is open when a phase raises."""
+
+    __slots__ = ("sim", "name", "phase", "span", "t0")
+
+    def __init__(self, sim, name: str, phase: int):
+        import jax
+        self.sim, self.name, self.phase = sim, name, phase
+        self.span = jax.profiler.TraceAnnotation("df." + name, phase=phase)
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+
+    def stop(self) -> None:
+        if self.span is None:
+            return
+        t1 = time.perf_counter()
+        self.span.__exit__(None, None, None)
+        self.span = None
+        st = self.sim.stage_time_s
+        st[self.name] = st.get(self.name, 0.0) + t1 - self.t0
+
+    def next(self, name: str) -> "_Stage":
+        """Stop this stage and start ``name`` in the same phase."""
+        self.stop()
+        return _Stage(self.sim, name, self.phase)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
+
+
+class _NoStage:
+    """The stage clock with ``profile_stages`` off: no clock read, no
+    span; ``next()`` returns itself."""
+
+    __slots__ = ()
+
+    def stop(self) -> None:
+        pass
+
+    def next(self, name: str) -> "_NoStage":
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NO_STAGE = _NoStage()
+
+
 @dataclass(frozen=True)
 class SimParams:
     seed: int = 0
@@ -157,7 +220,10 @@ class SimParams:
     notify_clear_frac: float = 0.5
     notify_delay_phases: int = 1
     notify_penalty_s: float = 300e-6
-    #: accumulate per-stage wall times into sim.stage_time_s (perf_sim.py)
+    #: the stage clock (`DragonflySimulator.stage`): each stage's host
+    #: seconds add into sim.stage_time_s, and the same extent is the
+    #: profiler span ``df.<stage>``.  Off: no clock read, no span, no
+    #: wait for the device.
     profile_stages: bool = False
 
     @property
@@ -421,12 +487,26 @@ class DragonflySimulator:
 
         return _Backend()
 
-    # ------------------------------------------------------------- internals
-    def _stage(self, name: str, t0: float) -> float:
-        t1 = time.perf_counter()
-        self.stage_time_s[name] = self.stage_time_s.get(name, 0.0) + t1 - t0
-        return t1
+    # ---------------------------------------------------------- stage clock
+    def stage(self, name: str, phase: int | None = None):
+        """The stage ``name`` of phase ``phase`` (default: the phase about
+        to run), started now: a context manager, or an object whose
+        ``stop()`` ends it and whose ``next(name)`` ends it and starts
+        ``name``.  With ``profile_stages`` off it does nothing (one flag
+        test).
 
+        Stages of a phase: ``candidates``, ``estimate``, ``fixed_point``
+        and ``finalize`` tile `run_phase`; on the jax backend
+        ``transfer``, ``device_wait`` and ``fetch`` nest inside
+        ``fixed_point``; ``policy`` is the routing policy's decision and
+        feedback around a phase (`traffic.run_iteration_engine`, the
+        tenancy round)."""
+        if not self.params.profile_stages:
+            return _NO_STAGE
+        return _Stage(self, name, self.phase_index if phase is None
+                      else phase)
+
+    # ------------------------------------------------------------- internals
     def _bg_flows(self, allocation: Allocation | None = None):
         p = self.params
         n = p.bg_flows_per_phase
@@ -589,7 +669,10 @@ class DragonflySimulator:
                                 plan=plan, tenants=tenants)
         if ctx["result"] is not None:
             return ctx["result"]
-        return self._phase_finish(ctx, self._run_kernel(ctx))
+        try:
+            return self._phase_finish(ctx, self._run_kernel(ctx))
+        finally:
+            ctx["stage"].stop()          # the open stage, if either raised
 
     def _phase_begin(self, src_nodes, dst_nodes, bytes_,
                      policy: RoutingPolicy,
@@ -611,10 +694,10 @@ class DragonflySimulator:
         skipped (``ctx["score0"]`` stays None)."""
         p = self.params
         topo = self.topo
-        prof = p.profile_stages
-        t0 = time.perf_counter() if prof else 0.0
         if tenants is not None and allocation is not None:
             raise ValueError("pass either allocation= or tenants=, not both")
+        phase = self.phase_index
+        st = self.stage("candidates", phase)
         tenant_of = None
 
         # --- fault state for this phase (docs/faults.md) -------------------
@@ -680,7 +763,9 @@ class DragonflySimulator:
                     tenant_of = tenant_of[idx]
                 n_app = p.max_flows
         if n_app == 0 and not (p.bg_enable and p.bg_flows_per_phase):
-            return {"result": FlowResult(*(np.zeros(0),) * 5, 0.0)}
+            st.stop()
+            return {"result": FlowResult(*(np.zeros(0),) * 5, 0.0),
+                    "stage": st}
 
         bg = self._bg_flows(tenants.union_allocation if tenants is not None
                             else allocation)
@@ -756,8 +841,7 @@ class DragonflySimulator:
             cand_mask = ~((fdead[safe] & valid).any(axis=-1)) \
                 & ~row_dead[:, None]
             stranded = ~cand_mask.any(axis=-1)
-        if prof:
-            t0 = self._stage("candidates", t0)
+        st = st.next("estimate")
 
         # --- stale & noisy congestion estimate (phantom congestion) --------
         noise = self.rng.lognormal(0.0, p.phantom_sigma, size=topo.n_links)
@@ -840,8 +924,7 @@ class DragonflySimulator:
         if backend == "numpy":
             nic_load = np.bincount(nic_ids, weights=size_inst,
                                    minlength=topo.n_links)
-        if prof:
-            t0 = self._stage("estimate", t0)
+        st = st.next("fixed_point")
         return {
             "result": None, "backend": backend,
             "n_app": n_app, "n_all": n_all, "ncand": ncand,
@@ -860,7 +943,7 @@ class DragonflySimulator:
             "fstate": fstate, "notify_vis": notify_vis,
             "est_notify": est_notify,
             "tenants": tenants, "tenant_of": tenant_of,
-            "allocation": allocation, "t0": t0,
+            "allocation": allocation, "phase": phase, "stage": st,
         }
 
     def _run_kernel(self, ctx: dict):
@@ -891,8 +974,6 @@ class DragonflySimulator:
         notification-state updates, NIC counters, tenant breakdown."""
         p = self.params
         topo = self.topo
-        prof = p.profile_stages
-        t0 = ctx["t0"]
         n_app, ncand = ctx["n_app"], ctx["ncand"]
         safe, valid, is_nonmin = ctx["safe"], ctx["valid"], ctx["is_nonmin"]
         pair_links, pair_fc = ctx["pair_links"], ctx["pair_fc"]
@@ -915,8 +996,7 @@ class DragonflySimulator:
                 cand_flag = (notify_vis[safe[:n_app]]
                              & valid[:n_app]).any(axis=-1)
                 flow_notified = (cand_flag * np.asarray(w_app)).sum(axis=-1)
-        if prof:
-            t0 = self._stage("fixed_point", t0)
+        st = ctx["stage"] = ctx["stage"].next("finalize")
 
         flits, packets = self._flits_packets(size_all)
         win = (packets + MAX_OUTSTANDING_PACKETS // 2) \
@@ -1037,8 +1117,7 @@ class DragonflySimulator:
             bytes_t = np.bincount(tenant_of, weights=size[:n_app],
                                   minlength=K)
             t_nonmin = nm_t / np.maximum(bytes_t, 1e-9)
-        if prof:
-            self._stage("finalize", t0)
+        st.stop()
         return FlowResult(
             t_us=t_us[:n_app],
             latency_us=app_lat,
@@ -1232,27 +1311,33 @@ def run_phase_batch(calls) -> list:
     columns (same mix, different victim arms) advance round-for-round
     with every cell's phase kernel batched into one dispatch
     (docs/interference.md)."""
-    ctxs = [sim._phase_begin(**kw) for sim, kw in calls]
-    outs: dict = {}
-    groups: dict = {}
-    for i, ((sim, _), ctx) in enumerate(zip(calls, ctxs)):
-        if ctx["result"] is None and ctx["backend"] == "jax":
-            from repro.dragonfly.jax_backend import batch_signature
-            groups.setdefault(batch_signature(sim, ctx), []).append(i)
-    for idxs in groups.values():
-        if len(idxs) < 2:
-            continue
-        from repro.dragonfly.jax_backend import fixed_point_jax_batch
-        batch = [(calls[i][0], ctxs[i]) for i in idxs]
-        for i, o in zip(idxs, fixed_point_jax_batch(batch)):
-            outs[i] = o
-    results = []
-    for i, ((sim, _), ctx) in enumerate(zip(calls, ctxs)):
-        if ctx["result"] is not None:
-            results.append(ctx["result"])
-            continue
-        out = outs.get(i)
-        if out is None:
-            out = sim._run_kernel(ctx)
-        results.append(sim._phase_finish(ctx, out))
-    return results
+    ctxs: list = []
+    try:
+        for sim, kw in calls:
+            ctxs.append(sim._phase_begin(**kw))
+        outs: dict = {}
+        groups: dict = {}
+        for i, ((sim, _), ctx) in enumerate(zip(calls, ctxs)):
+            if ctx["result"] is None and ctx["backend"] == "jax":
+                from repro.dragonfly.jax_backend import batch_signature
+                groups.setdefault(batch_signature(sim, ctx), []).append(i)
+        for idxs in groups.values():
+            if len(idxs) < 2:
+                continue
+            from repro.dragonfly.jax_backend import fixed_point_jax_batch
+            batch = [(calls[i][0], ctxs[i]) for i in idxs]
+            for i, o in zip(idxs, fixed_point_jax_batch(batch)):
+                outs[i] = o
+        results = []
+        for i, ((sim, _), ctx) in enumerate(zip(calls, ctxs)):
+            if ctx["result"] is not None:
+                results.append(ctx["result"])
+                continue
+            out = outs.get(i)
+            if out is None:
+                out = sim._run_kernel(ctx)
+            results.append(sim._phase_finish(ctx, out))
+        return results
+    finally:
+        for ctx in ctxs:                 # the open stages, if a phase raised
+            ctx["stage"].stop()

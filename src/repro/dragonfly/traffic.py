@@ -269,31 +269,38 @@ def run_iteration_engine(sim: DragonflySimulator, alloc: Allocation,
     NumPy-shaped batch), modes applied per flow inside the simulator, and
     one TelemetryBus publish of the phase's per-flow (L, s) — the
     counters are read after the send, so the policy stays one phase
-    behind (paper §4.3), paying the same §5.1 counter-read overhead."""
+    behind (paper §4.3), paying the same §5.1 counter-read overhead.
+
+    The decision and the publish are the simulator's ``policy`` stage
+    (``SimParams.profile_stages``), in the phase they decide."""
     base_policy = base_policy or RoutingPolicy(RoutingMode.ADAPTIVE_0)
     total_us = 0.0
     lat, st, nmf, wts = [], [], [], []
     mode_bytes: dict = {}
     nodes = np.asarray(alloc.nodes)
     for (s, d, b) in phases:
-        batch = DecisionBatch.of(b, site=site, kind=kind)
-        modes = engine.decide(batch)          # ONE call for the whole phase
+        phase = sim.phase_index
+        with sim.stage("policy", phase):
+            batch = DecisionBatch.of(b, site=site, kind=kind)
+            modes = engine.decide(batch)      # ONE call for the whole phase
         plan = sim.plan_for(nodes[s], nodes[d], b) if use_plans else None
         res = sim.run_phase(nodes[s], nodes[d], b, base_policy, alloc,
                             modes=modes, plan=plan)
         # post-send counter read (never delays the message itself)
-        if res.t_us.size == len(batch):
-            engine.bus.publish_flow_arrays(res.latency_us,
-                                           res.stalls_per_flit,
-                                           notified=res.notified)
-        elif res.t_us.size:
-            # the simulator statistically subsampled the phase: publish
-            # the phase-mean sample (engine broadcasts it over the batch)
-            engine.bus.publish_flow_arrays(
-                [float(res.latency_us.mean())],
-                [float(res.stalls_per_flit.mean())],
-                notified=None if res.notified is None
-                else [float(res.notified.mean())])
+        with sim.stage("policy", phase):
+            if res.t_us.size == len(batch):
+                engine.bus.publish_flow_arrays(res.latency_us,
+                                               res.stalls_per_flit,
+                                               notified=res.notified)
+            elif res.t_us.size:
+                # the simulator statistically subsampled the phase:
+                # publish the phase-mean sample (engine broadcasts it
+                # over the batch)
+                engine.bus.publish_flow_arrays(
+                    [float(res.latency_us.mean())],
+                    [float(res.stalls_per_flit.mean())],
+                    notified=None if res.notified is None
+                    else [float(res.notified.mean())])
         host = sim.params.host_overhead_us * sim.rng.lognormal(
             0.0, sim.params.host_noise_sigma) + counter_read_overhead_us
         total_us += res.phase_time_us + host

@@ -171,6 +171,10 @@ class InterferenceEngine:
         epoch transition each engine-armed tenant's policy samples are
         reset via ``on_fault_epoch`` — measurements from the previous
         link set would contaminate Algorithm 1's regime decisions.
+
+        Each engine-armed tenant's ``decide`` and its TelemetryBus
+        publish are the simulator's ``policy`` stage
+        (``SimParams.profile_stages``), in the round's phase.
         """
         sim = DragonflySimulator(topo if topo is not None else self.topo,
                                  self.params, faults=faults)
@@ -197,6 +201,7 @@ class InterferenceEngine:
                         if w.is_engine_arm:
                             engines[k].on_fault_epoch(
                                 scoped_site_filter(w.name))
+            phase = sim.phase_index
             srcs, dsts, byts, mode_l, counts = [], [], [], [], []
             for k, w in enumerate(workloads):
                 s, d, b = phases[k][r % len(phases[k])]
@@ -206,11 +211,12 @@ class InterferenceEngine:
                 byts.append(np.asarray(b, dtype=np.float64))
                 counts.append(len(b))
                 if w.is_engine_arm:
-                    batch = DecisionBatch.of(
-                        b, site=(w.name, w.pattern),
-                        kind=PATTERN_KIND.get(w.pattern, KIND_PT2PT))
-                    mode_l.append(np.asarray(engines[k].decide(batch),
-                                             dtype=object))
+                    with sim.stage("policy", phase):
+                        batch = DecisionBatch.of(
+                            b, site=(w.name, w.pattern),
+                            kind=PATTERN_KIND.get(w.pattern, KIND_PT2PT))
+                        mode_l.append(np.asarray(engines[k].decide(batch),
+                                                 dtype=object))
                 else:
                     m = np.empty(len(b), dtype=object)
                     m[:] = w.arm
@@ -234,17 +240,19 @@ class InterferenceEngine:
                     # (notified exposure sliced per tenant like (L, s):
                     # no cross-tenant leakage through the new counter)
                     nf = res.notified
-                    if rows.size == counts[k]:
-                        engines[k].bus.publish_flow_arrays(
-                            res.latency_us[rows], res.stalls_per_flit[rows],
-                            notified=None if nf is None else nf[rows])
-                    else:
-                        # statistically subsampled: phase-mean sample
-                        engines[k].bus.publish_flow_arrays(
-                            [float(res.latency_us[rows].mean())],
-                            [float(res.stalls_per_flit[rows].mean())],
-                            notified=None if nf is None
-                            else [float(nf[rows].mean())])
+                    with sim.stage("policy", phase):
+                        if rows.size == counts[k]:
+                            engines[k].bus.publish_flow_arrays(
+                                res.latency_us[rows],
+                                res.stalls_per_flit[rows],
+                                notified=None if nf is None else nf[rows])
+                        else:
+                            # statistically subsampled: phase-mean sample
+                            engines[k].bus.publish_flow_arrays(
+                                [float(res.latency_us[rows].mean())],
+                                [float(res.stalls_per_flit[rows].mean())],
+                                notified=None if nf is None
+                                else [float(nf[rows].mean())])
                 host = p.host_overhead_us * sim.rng.lognormal(
                     0.0, p.host_noise_sigma)
                 if w.is_engine_arm:
