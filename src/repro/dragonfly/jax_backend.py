@@ -60,7 +60,9 @@ import jax.numpy as jnp
 
 from repro.compat.runtime import on_tpu, resolve_pallas_kernel
 from repro.kernels.segment_sum.ref import segment_sum_ref
-from repro.kernels.segment_sum.segment_sum import segment_sum_pallas
+from repro.kernels.segment_sum.segment_sum import (
+    BLOCK, dense_grid_steps, segment_sum_pallas, segment_sum_sorted_pallas,
+    sorted_grid_steps, sorted_schedule)
 
 #: diagnostics: executed-pipeline counters ("single"/"batched" jitted
 #: dispatches).  Tests, perf_sim and chip_smoke.py assert on deltas to
@@ -76,6 +78,13 @@ PIPELINE_CALLS = {"single": 0, "batched": 0}
 #: first phase counts as every other.
 TRANSFER = {"h2d_copies": 0, "h2d_bytes": 0, "d2h_bytes": 0}
 
+#: Pallas segment-sum calls of the pipeline, counted always from each
+#: phase's statics and shapes: ``sorted_calls`` (plan-reused heads,
+#: `segment_sum_sorted_pallas`), ``dense_calls`` (the NIC sum, the
+#: unsorted tails and planless pair lists, `segment_sum_pallas`) and
+#: their ``grid_steps``.  Nothing counts where the kernel is off.
+SEGSUM = {"sorted_calls": 0, "dense_calls": 0, "grid_steps": 0}
+
 #: pair-list padding buckets (docs/performance.md).  Plan-reused phases
 #: only redraw the ~bg_flows_per_phase background rows, so their pair
 #: tail is padded to a small bucket; planless phases redraw everything
@@ -87,10 +96,11 @@ TRANSFER = {"h2d_copies": 0, "h2d_bytes": 0, "d2h_bytes": 0}
 _PAIR_BUCKET_PLAN = 1024
 _PAIR_BUCKET_FULL = 4096
 
-#: block width of the sorted-head prefix sum.  The pinned sorted pair
-#: list is padded to a multiple of this (zero-mask entries on the last
-#: link), so the blocked cumsum needs no remainder handling.
-_CUMSUM_BLOCK = 1024
+#: block width of the sorted head.  The pinned sorted pair list is
+#: padded to a multiple of this (zero-mask entries on the last link), so
+#: the blocked cumsum needs no remainder handling and the sorted
+#: kernel's pair blocks never reach into the unsorted tail.
+_HEAD_BLOCK = BLOCK
 
 
 def kernel_mode(params) -> tuple:
@@ -112,10 +122,11 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
                     link_queue_s, hl_rows, bias_rows, posinf, neginf,
                     t_rows, noise_scale, gnoise, size_all, cap_window,
                     nic_ids, pair_links, pair_fc, pair_mask, seg_off,
-                    window_s, feedback_rho0, rho_threshold, queue_delay_ns,
-                    qwait_fraction, stall_gain, nic_latency_ns,
-                    hop_latency_ns, *, n_spray: int, n_links: int,
-                    use_kernel: bool, interpret: bool, p_sorted: int):
+                    sched, window_s, feedback_rho0, rho_threshold,
+                    queue_delay_ns, qwait_fraction, stall_gain,
+                    nic_latency_ns, hop_latency_ns, *, n_spray: int,
+                    n_links: int, use_kernel: bool, interpret: bool,
+                    p_sorted: int):
     """One phase: score -> spray -> lax.scan feedback -> observables.
 
     Pure in its arguments; statics select the segment-sum implementation
@@ -124,13 +135,17 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     never enters the graph.  ``pair_mask`` zeroes the bucket-padding
     entries so they are exact no-ops in every accumulation.
 
-    ``p_sorted``/``seg_off``: the first ``p_sorted`` pair entries are
-    pre-sorted by link id on the host (the plan-pinned app pairs), with
-    ``seg_off`` their [n_links+1] segment offsets.  That head reduces
-    via cumsum-diff — XLA CPU runs it ~5x faster than the scatter-add
-    lowering of `segment_sum` — while the unsorted tail (the per-phase
-    background sliver) still scatter-adds.  The Pallas-kernel path keeps
-    the scatter layout its kernel is written for.
+    ``p_sorted``/``seg_off``/``sched``: the first ``p_sorted`` pair
+    entries are pre-sorted by link id on the host (the plan-pinned app
+    pairs), with ``seg_off`` their [n_links+1] segment offsets and
+    ``sched`` the sorted kernel's visit list built from them.  On the
+    Pallas path that head goes through `segment_sum_sorted_pallas`, each
+    link block reading only its own pair blocks; otherwise it reduces
+    via cumsum-diff, which XLA CPU runs ~5x faster than the scatter-add
+    lowering of `segment_sum`.  The unsorted tail (the per-phase
+    background sliver), the NIC sum and planless pair lists
+    (``p_sorted == 0``) scatter-add: the dense kernel, or
+    `segment_sum`.
     """
     def seg_sum(vals, ids):
         with jax.named_scope("segsum"):
@@ -140,21 +155,27 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
             return segment_sum_ref(vals, ids, n_links)
 
     def pair_sum(vals):
-        if use_kernel or not p_sorted:
+        if not p_sorted:
             return seg_sum(vals, pair_links)
         with jax.named_scope("segsum"):
-            # blocked prefix sum over the sorted head: per-block cumsums
-            # vectorize across rows where XLA CPU's 1-D cumsum does not,
-            # and only the [n_links+1] boundary prefixes materialize.
-            nb = p_sorted // _CUMSUM_BLOCK
-            within = jnp.cumsum(
-                vals[:p_sorted].reshape(nb, _CUMSUM_BLOCK), axis=1)
-            base = jnp.concatenate([jnp.zeros(1, vals.dtype),
-                                    jnp.cumsum(within[:, -1])])
-            i, j = seg_off // _CUMSUM_BLOCK, seg_off % _CUMSUM_BLOCK
-            w_in = within[jnp.minimum(i, nb - 1), jnp.maximum(j - 1, 0)]
-            pref = base[i] + jnp.where(j > 0, w_in, 0.0)
-            out = pref[1:] - pref[:-1]
+            if use_kernel:
+                # the whole list goes in: the schedule reads only the head
+                out = segment_sum_sorted_pallas(vals, pair_links, sched,
+                                                n_links, interpret=interpret)
+            else:
+                # blocked prefix sum over the sorted head: per-block
+                # cumsums vectorize across rows where XLA CPU's 1-D
+                # cumsum does not, and only the [n_links+1] boundary
+                # prefixes materialize.
+                nb = p_sorted // _HEAD_BLOCK
+                within = jnp.cumsum(
+                    vals[:p_sorted].reshape(nb, _HEAD_BLOCK), axis=1)
+                base = jnp.concatenate([jnp.zeros(1, vals.dtype),
+                                        jnp.cumsum(within[:, -1])])
+                i, j = seg_off // _HEAD_BLOCK, seg_off % _HEAD_BLOCK
+                w_in = within[jnp.minimum(i, nb - 1), jnp.maximum(j - 1, 0)]
+                pref = base[i] + jnp.where(j > 0, w_in, 0.0)
+                out = pref[1:] - pref[:-1]
         if vals.shape[0] > p_sorted:
             out = out + seg_sum(vals[p_sorted:], pair_links[p_sorted:])
         return out
@@ -237,7 +258,7 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
 
 #: positional index of cand_mask in _phase_pipeline's signature
 _MASK_ARG = 4
-_N_ARGS = 29
+_N_ARGS = 30
 
 
 @functools.lru_cache(maxsize=None)
@@ -288,19 +309,20 @@ def _device_plan(plan, n_links: int) -> dict:
     device side of the cache too.
 
     The pair list is pinned SORTED BY LINK ID (a host-side argsort, paid
-    once per plan), padded to a `_CUMSUM_BLOCK` multiple with zero-mask
+    once per plan), padded to a `_HEAD_BLOCK` multiple with zero-mask
     entries on the last link (sort order survives, padded values are
-    exactly 0.0), with its segment offsets alongside — the pipeline's
-    blocked cumsum-diff reduction needs sorted block-aligned segments,
-    and scatter-based consumers are order-insensitive, so the reorder is
-    transparent to the Pallas path.  The plan's own (host) arrays keep
-    original order: numpy-backend parity is untouched.  These one-off
-    uploads stay out of `TRANSFER`, which counts a phase's own."""
+    exactly 0.0), with its segment offsets alongside and the sorted
+    kernel's visit list (`sorted_schedule`) built from them on the host:
+    both of the pipeline's head reductions, the Pallas kernel's and the
+    blocked cumsum-diff, need sorted block-aligned segments.  The plan's
+    own (host) arrays keep original order: numpy-backend parity is
+    untouched.  These one-off uploads stay out of `TRANSFER`, which
+    counts a phase's own."""
     dev = plan.device_bundle
     if dev is None:
         pl = np.asarray(plan.pair_links)
         order = np.argsort(pl, kind="stable")
-        p_pad = _padded_len(pl.shape[0], _CUMSUM_BLOCK)
+        p_pad = _padded_len(pl.shape[0], _HEAD_BLOCK)
         links = np.full(p_pad, n_links - 1, dtype=np.int32)
         links[:pl.shape[0]] = pl[order]
         fc = np.zeros(p_pad, dtype=np.int32)
@@ -318,6 +340,7 @@ def _device_plan(plan, n_links: int) -> dict:
             "pair_fc": jnp.asarray(fc),
             "pair_mask": jnp.asarray(mask),
             "seg_off": jnp.asarray(off, dtype=jnp.int32),
+            "sched": jnp.asarray(sorted_schedule(off)),
             "p_sorted": p_pad,
         }
         plan.device_bundle = dev
@@ -362,7 +385,7 @@ def padded_pair_len(ctx: dict) -> int:
     plan = ctx["plan"]
     if plan is not None:
         p_app = int(plan.pair_links.shape[0])
-        head = _padded_len(p_app, _CUMSUM_BLOCK)
+        head = _padded_len(p_app, _HEAD_BLOCK)
         n_bg = P - p_app
         if n_bg == 0:
             return head
@@ -379,7 +402,7 @@ def _prepare_inputs(sim, ctx: dict):
 
     if plan is not None:
         dev = _device_plan(plan, int(tp.n_links))
-        seg_off = dev["seg_off"]
+        seg_off, sched = dev["seg_off"], dev["sched"]
         p_sorted = dev["p_sorted"]
         n_all = ctx["safe"].shape[0]
         if n_all > n_app:               # background rows ride along
@@ -422,6 +445,7 @@ def _prepare_inputs(sim, ctx: dict):
             ctx["pair_links"], ctx["pair_fc"],
             _padded_len(ctx["pair_links"].shape[0], _PAIR_BUCKET_FULL))
         seg_off = jnp.zeros(int(tp.n_links) + 1, dtype=jnp.int32)
+        sched = jnp.zeros(3, dtype=jnp.int32)
         p_sorted = 0                     # planless: scatter everything
 
     cm = ctx["cand_mask"]
@@ -434,7 +458,7 @@ def _prepare_inputs(sim, ctx: dict):
         _f32(ctx["t_rows"]), _f32(ctx["noise_scale"]),
         _put(np.asarray(ctx["gnoise"], dtype=np.float32)),
         _f32(ctx["size_all"]), _f32(ctx["cap_window"]), nic_ids,
-        pair_links, pair_fc, pair_mask, seg_off,
+        pair_links, pair_fc, pair_mask, seg_off, sched,
         _f32(ctx["window_s"]), _f32(p.feedback_rho0),
         _f32(p.rho_threshold), _f32(p.queue_delay_ns),
         _f32(p.qwait_fraction), _f32(p.stall_gain),
@@ -442,7 +466,29 @@ def _prepare_inputs(sim, ctx: dict):
     )
     statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links),
                *kernel_mode(p), p_sorted)
+    if statics[2]:
+        _count_segsum(statics, int(pair_links.shape[0]),
+                      int(nic_ids.shape[0]))
     return inputs, statics
+
+
+def _count_segsum(statics, n_pairs: int, n_rows: int):
+    """Add one phase's Pallas segment sums to `SEGSUM`: the NIC sum over
+    ``n_rows``, and each of the ``n_spray + 1`` pair reductions (the
+    first spray, each feedback iteration, ``load_q``) over the sorted
+    head and the unsorted rest of ``n_pairs``."""
+    n_spray, n_links, _, _, p_sorted = statics
+    reductions = n_spray + 1
+    steps = dense_grid_steps(n_rows, n_links)
+    dense = 1
+    if p_sorted:
+        SEGSUM["sorted_calls"] += reductions
+        steps += reductions * sorted_grid_steps(p_sorted, n_links)
+    if n_pairs > p_sorted:
+        dense += reductions
+        steps += reductions * dense_grid_steps(n_pairs - p_sorted, n_links)
+    SEGSUM["dense_calls"] += dense
+    SEGSUM["grid_steps"] += steps
 
 
 def batch_signature(sim, ctx: dict) -> tuple:
@@ -453,7 +499,7 @@ def batch_signature(sim, ctx: dict) -> tuple:
             *kernel_mode(sim.params), tuple(ctx["safe"].shape),
             padded_pair_len(ctx),
             0 if plan is None else _padded_len(plan.pair_links.shape[0],
-                                               _CUMSUM_BLOCK),
+                                               _HEAD_BLOCK),
             ctx["cand_mask"] is not None)
 
 

@@ -163,6 +163,68 @@ def test_pallas_kernel_on_agrees_with_off():
     _assert_close(results["on"], results["off"], rtol=1e-4)
 
 
+#: 1,400 links: two 1024-wide link blocks, the second ragged
+TWO_BLOCKS = DragonflyTopology(TopologyParams(
+    n_groups=5, chassis_per_group=2, blades_per_chassis=6))
+
+
+def _reused_plan_phases(knob, n_phases=2, backend="jax"):
+    """Plan-reused phases (sorted head) with the background tail."""
+    src, dst, size = _flows(TWO_BLOCKS, seed=19, n=300)
+    sim = DragonflySimulator(
+        TWO_BLOCKS, SimParams(seed=5, backend=backend, pallas_kernel=knob))
+    plan = sim.plan_for(src, dst, size)
+    pol = RoutingPolicy(RoutingMode.ADAPTIVE_0)
+    return [sim.run_phase(src, dst, size, pol, plan=plan)
+            for _ in range(n_phases)], plan
+
+
+def test_pallas_kernel_on_agrees_with_off_on_a_reused_plan():
+    """The sorted kernel over a plan's head plus the dense kernel over
+    its background tail replay the cumsum-diff path in a phase.  From
+    the second phase on, the carried queues amplify the cumsum-diff's
+    own float32 rounding (1.3e-3 off float64 numpy here), so the kernel
+    path is held to numpy there, at the same tolerance."""
+    on, _ = _reused_plan_phases("on")
+    off, _ = _reused_plan_phases("off", 1)
+    ref, _ = _reused_plan_phases("off", backend="numpy")
+    _assert_close(on[0], off[0], rtol=1e-4)
+    for r_on, r_ref in zip(on, ref):
+        _assert_close(r_on, r_ref, rtol=1e-4)
+
+
+def test_segsum_counts_a_reused_plan_and_a_planless_phase():
+    from repro.dragonfly import jax_backend
+
+    def delta(run):
+        before = dict(jax_backend.SEGSUM)
+        out = run()
+        return out, {k: v - before[k] for k, v in jax_backend.SEGSUM.items()}
+
+    # 300 app + 16 background rows; 5 pair reductions a phase (4 sprays
+    # + load_q); 2 link blocks
+    (_, plan), reused = delta(lambda: _reused_plan_phases("on", 1))
+    head_blocks = plan.device_bundle["p_sorted"] // 1024
+    assert reused == {"sorted_calls": 5, "dense_calls": 1 + 5,
+                      "grid_steps": 2 + 5 * (head_blocks + 2) + 5 * 2}
+
+    src, dst, size = _flows(TWO_BLOCKS, seed=19, n=300)
+    sim = DragonflySimulator(
+        TWO_BLOCKS, SimParams(seed=5, backend="jax", pallas_kernel="on"))
+    _, planless = delta(lambda: sim.run_phase(
+        src, dst, size, RoutingPolicy(RoutingMode.ADAPTIVE_0)))
+    # the whole pair list dense, padded to 4096-pair buckets: 4k pair
+    # blocks x 2 link blocks a reduction
+    assert planless["sorted_calls"] == 0
+    assert planless["dense_calls"] == 1 + 5
+    assert planless["grid_steps"] > 2
+    assert (planless["grid_steps"] - 2) % (5 * 4 * 2) == 0
+
+    off_before = dict(jax_backend.SEGSUM)
+    _reused_plan_phases("off", 1)
+    assert jax_backend.SEGSUM == off_before
+
+
 def test_pallas_kernel_auto_is_off_on_cpu():
     from repro.compat.runtime import on_tpu, resolve_pallas_kernel
     if not on_tpu():

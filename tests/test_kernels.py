@@ -161,3 +161,108 @@ def test_segment_sum_empty_and_untouched_segments():
     out2 = segment_sum_op(vals, ids, 8)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(out),
                                atol=1e-7)
+
+
+# --------------------------------------------------------------------------
+# sorted segment_sum: a plan's link-sorted pairs, each link block visiting
+# only its own pair blocks (scalar-prefetched schedule, 1024-wide blocks).
+# --------------------------------------------------------------------------
+def _sorted_pairs(case, rng):
+    """(sorted ids, values, n_links) of one layout the schedule must
+    cover; values are random and nonzero except where padding says."""
+    if case == "empty_segment_blocks":          # blocks 1 and 2 hold none
+        ids, n_links = np.r_[rng.integers(0, 500, 300),
+                             rng.integers(3500, 4096, 900)], 4096
+    elif case == "link_crosses_pair_block":     # link 100: pairs 500-1999
+        ids, n_links = np.r_[rng.integers(0, 100, 500), np.full(1500, 100),
+                             rng.integers(101, 2000, 600)], 2000
+    elif case == "pair_block_straddles_segment_blocks":
+        ids, n_links = np.r_[rng.integers(0, 1000, 1500),
+                             rng.integers(1000, 1050, 1024),
+                             rng.integers(1050, 2048, 700)], 2048
+    elif case == "one_link":
+        ids, n_links = np.full(3000, 1500), 2048
+    elif case == "ragged_links":
+        ids, n_links = rng.integers(0, 2500, 5000), 2500
+    elif case == "zero_padding_on_last_link":   # as the engine pins a plan
+        ids, n_links = np.r_[rng.integers(0, 3000, 2300),
+                             np.full(772, 2999)], 3000
+    elif case == "single_pair_block":
+        ids, n_links = rng.integers(0, 1500, 700), 1500
+    ids = np.sort(ids)
+    vals = rng.random(ids.shape[0]).astype(np.float32) + 0.5
+    if case == "zero_padding_on_last_link":
+        vals[2300:] = 0.0
+    return ids, vals, n_links
+
+
+def _schedule_of(ids, n_links):
+    from repro.kernels.segment_sum.segment_sum import sorted_schedule
+    off = np.zeros(n_links + 1, dtype=np.int64)
+    np.cumsum(np.bincount(ids, minlength=n_links), out=off[1:])
+    return sorted_schedule(off)
+
+
+@pytest.mark.parametrize("case", [
+    "empty_segment_blocks", "link_crosses_pair_block",
+    "pair_block_straddles_segment_blocks", "one_link", "ragged_links",
+    "zero_padding_on_last_link", "single_pair_block"])
+def test_sorted_segment_sum_matches_ref(case):
+    from repro.kernels.segment_sum import segment_sum_ref
+    from repro.kernels.segment_sum.segment_sum import (
+        segment_sum_sorted_pallas, sorted_grid_steps)
+
+    ids, vals, n_links = _sorted_pairs(case, np.random.default_rng(7))
+    sched = _schedule_of(ids, n_links)
+    assert sched.shape == (3 * sorted_grid_steps(ids.shape[0], n_links),)
+    out = segment_sum_sorted_pallas(jnp.asarray(vals), jnp.asarray(ids),
+                                    jnp.asarray(sched), n_links,
+                                    interpret=True)
+    ref = segment_sum_ref(jnp.asarray(vals), jnp.asarray(ids), n_links)
+    assert out.shape == (n_links,)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_sorted_segment_sum_reads_no_pair_past_its_schedule():
+    """The engine hands over the whole pair list: the unsorted tail
+    past the sorted head is left to the dense kernel."""
+    from repro.kernels.segment_sum import segment_sum_ref
+    from repro.kernels.segment_sum.segment_sum import (
+        segment_sum_sorted_pallas)
+
+    rng = np.random.default_rng(8)
+    head = np.sort(rng.integers(0, 3000, 2048))
+    tail = rng.integers(0, 3000, 1024)
+    vals = rng.random(3072).astype(np.float32)
+    out = segment_sum_sorted_pallas(
+        jnp.asarray(vals), jnp.asarray(np.r_[head, tail]),
+        jnp.asarray(_schedule_of(head, 3000)), 3000, interpret=True)
+    ref = segment_sum_ref(jnp.asarray(vals[:2048]), jnp.asarray(head), 3000)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_sorted_segment_sum_vmaps_lanes_with_their_own_schedules():
+    """The lockstep batch: lanes hold different plans, so different
+    schedules of one length."""
+    import jax
+
+    from repro.kernels.segment_sum import segment_sum_ref
+    from repro.kernels.segment_sum.segment_sum import (
+        segment_sum_sorted_pallas)
+
+    rng = np.random.default_rng(9)
+    ids = np.stack([np.sort(rng.integers(0, 3000, 4096)),
+                    np.sort(rng.integers(2000, 2100, 4096))])
+    vals = rng.random((2, 4096)).astype(np.float32)
+    scheds = np.stack([_schedule_of(i, 3000) for i in ids])
+    assert not np.array_equal(scheds[0], scheds[1])
+    out = jax.vmap(lambda v, i, s: segment_sum_sorted_pallas(
+        v, i, s, 3000, interpret=True))(jnp.asarray(vals),
+                                        jnp.asarray(ids),
+                                        jnp.asarray(scheds))
+    ref = jax.vmap(lambda v, i: segment_sum_ref(v, i, 3000))(
+        jnp.asarray(vals), jnp.asarray(ids))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)

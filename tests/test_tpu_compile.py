@@ -1,4 +1,4 @@
-"""The Pallas segment-sum compiles for a TPU v5e at the engine's widths.
+"""The Pallas segment sums compile for a TPU v5e at the engine's widths.
 
 Interpret-mode tests (tests/test_kernels.py) cannot see what Mosaic
 refuses: a 1-D block whose tiling differs from XLA's T(1024) layout of
@@ -16,7 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.dragonfly.jax_backend import _PAIR_BUCKET_FULL, _PAIR_BUCKET_PLAN
-from repro.kernels.segment_sum.segment_sum import segment_sum_pallas
+from repro.kernels.segment_sum.segment_sum import (
+    segment_sum_pallas, segment_sum_sorted_pallas, sorted_grid_steps)
 
 ARIES_LINKS = 56_448
 
@@ -71,4 +72,30 @@ def test_vmapped_segment_sum_compiles_for_v5e(one_chip):
     compiled = jax.jit(jax.vmap(
         lambda v, i: segment_sum_pallas(v, i, ARIES_LINKS))).lower(
             vals, ids).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes,n_head", [
+    (None, 3_760_128),                 # plan-pinned head, 120k flows
+    (None, 15_360),                    # the 512-rank protocol job's head
+    (2, 15_360),                       # the lockstep batch, lane by lane
+], ids=["aries_head", "protocol_head", "vmapped"])
+def test_sorted_segment_sum_compiles_for_v5e(one_chip, lanes, n_head):
+    """The engine hands the sorted kernel the whole pair list (head plus
+    the background bucket) and the plan's scalar-prefetched schedule."""
+    n_pairs = n_head + _PAIR_BUCKET_PLAN
+    lead = () if lanes is None else (lanes,)
+    vals = jax.ShapeDtypeStruct((*lead, n_pairs), jnp.float32,
+                                sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((*lead, n_pairs), jnp.int32,
+                               sharding=one_chip)
+    sched = jax.ShapeDtypeStruct(
+        (*lead, 3 * sorted_grid_steps(n_head, ARIES_LINKS)), jnp.int32,
+        sharding=one_chip)
+
+    def one(v, i, s):
+        return segment_sum_sorted_pallas(v, i, s, ARIES_LINKS)
+
+    fn = one if lanes is None else jax.vmap(one)
+    compiled = jax.jit(fn).lower(vals, ids, sched).compile()
     assert "tpu_custom_call" in compiled.as_text()
