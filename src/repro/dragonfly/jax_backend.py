@@ -82,8 +82,11 @@ TRANSFER = {"h2d_copies": 0, "h2d_bytes": 0, "d2h_bytes": 0}
 #: phase's statics and shapes: ``sorted_calls`` (plan-reused heads,
 #: `segment_sum_sorted_pallas`), ``dense_calls`` (the NIC sum, the
 #: unsorted tails and planless pair lists, `segment_sum_pallas`) and
-#: their ``grid_steps``.  Nothing counts where the kernel is off.
-SEGSUM = {"sorted_calls": 0, "dense_calls": 0, "grid_steps": 0}
+#: their ``grid_steps``; ``head_pairs`` and ``head_pad_pairs``, the real
+#: and the zero-mask pairs of each dispatched sorted head (`_head_len`).
+#: Nothing counts where the kernel is off.
+SEGSUM = {"sorted_calls": 0, "dense_calls": 0, "grid_steps": 0,
+          "head_pairs": 0, "head_pad_pairs": 0}
 
 #: pair-list padding buckets (docs/performance.md).  Plan-reused phases
 #: only redraw the ~bg_flows_per_phase background rows, so their pair
@@ -101,6 +104,11 @@ _PAIR_BUCKET_FULL = 4096
 #: the blocked cumsum needs no remainder handling and the sorted
 #: kernel's pair blocks never reach into the unsorted tail.
 _HEAD_BLOCK = BLOCK
+#: a head of P >= _HEAD_BUCKET_FROM pairs is padded to a multiple of
+#: 2**(floor(log2 P) - _HEAD_BUCKET_SHIFT), a smaller one to a
+#: `_HEAD_BLOCK` multiple (`_head_len`)
+_HEAD_BUCKET_FROM = 1 << 15
+_HEAD_BUCKET_SHIFT = 3
 
 
 def kernel_mode(params) -> tuple:
@@ -115,6 +123,19 @@ def kernel_mode(params) -> tuple:
 
 def _padded_len(n: int, bucket: int) -> int:
     return -(-max(int(n), 1) // bucket) * bucket
+
+
+def _head_len(p: int) -> int:
+    """Padded length of a plan's sorted head of ``p`` pairs.  From
+    32,768 pairs on, the next multiple of a bucket that grows with
+    ``p``, an eighth of its power of two: placements of one job size,
+    whose pair counts differ by a few percent, then share one compiled
+    shape, for a pad under 1/8 of the pairs.  Smaller heads keep
+    `_HEAD_BLOCK` multiples."""
+    p = max(int(p), 1)
+    if p < _HEAD_BUCKET_FROM:
+        return _padded_len(p, _HEAD_BLOCK)
+    return _padded_len(p, 1 << (p.bit_length() - 1 - _HEAD_BUCKET_SHIFT))
 
 
 # --------------------------------------------------------------- pipeline
@@ -309,9 +330,9 @@ def _device_plan(plan, n_links: int) -> dict:
     device side of the cache too.
 
     The pair list is pinned SORTED BY LINK ID (a host-side argsort, paid
-    once per plan), padded to a `_HEAD_BLOCK` multiple with zero-mask
-    entries on the last link (sort order survives, padded values are
-    exactly 0.0), with its segment offsets alongside and the sorted
+    once per plan), padded to `_head_len` with zero-mask entries on the
+    last link (sort order survives, padded values are exactly 0.0),
+    with its segment offsets alongside and the sorted
     kernel's visit list (`sorted_schedule`) built from them on the host:
     both of the pipeline's head reductions, the Pallas kernel's and the
     blocked cumsum-diff, need sorted block-aligned segments.  The plan's
@@ -322,7 +343,7 @@ def _device_plan(plan, n_links: int) -> dict:
     if dev is None:
         pl = np.asarray(plan.pair_links)
         order = np.argsort(pl, kind="stable")
-        p_pad = _padded_len(pl.shape[0], _HEAD_BLOCK)
+        p_pad = _head_len(pl.shape[0])
         links = np.full(p_pad, n_links - 1, dtype=np.int32)
         links[:pl.shape[0]] = pl[order]
         fc = np.zeros(p_pad, dtype=np.int32)
@@ -385,7 +406,7 @@ def padded_pair_len(ctx: dict) -> int:
     plan = ctx["plan"]
     if plan is not None:
         p_app = int(plan.pair_links.shape[0])
-        head = _padded_len(p_app, _HEAD_BLOCK)
+        head = _head_len(p_app)
         n_bg = P - p_app
         if n_bg == 0:
             return head
@@ -468,21 +489,25 @@ def _prepare_inputs(sim, ctx: dict):
                *kernel_mode(p), p_sorted)
     if statics[2]:
         _count_segsum(statics, int(pair_links.shape[0]),
-                      int(nic_ids.shape[0]))
+                      int(nic_ids.shape[0]),
+                      0 if plan is None else int(plan.pair_links.shape[0]))
     return inputs, statics
 
 
-def _count_segsum(statics, n_pairs: int, n_rows: int):
+def _count_segsum(statics, n_pairs: int, n_rows: int, p_head: int):
     """Add one phase's Pallas segment sums to `SEGSUM`: the NIC sum over
     ``n_rows``, and each of the ``n_spray + 1`` pair reductions (the
     first spray, each feedback iteration, ``load_q``) over the sorted
-    head and the unsorted rest of ``n_pairs``."""
+    head and the unsorted rest of ``n_pairs``; and the head's ``p_head``
+    real pairs with the zero-mask pairs that pad it, once a phase."""
     n_spray, n_links, _, _, p_sorted = statics
     reductions = n_spray + 1
     steps = dense_grid_steps(n_rows, n_links)
     dense = 1
     if p_sorted:
         SEGSUM["sorted_calls"] += reductions
+        SEGSUM["head_pairs"] += p_head
+        SEGSUM["head_pad_pairs"] += p_sorted - p_head
         steps += reductions * sorted_grid_steps(p_sorted, n_links)
     if n_pairs > p_sorted:
         dense += reductions
@@ -498,8 +523,7 @@ def batch_signature(sim, ctx: dict) -> tuple:
     return (int(sim.topo.n_links), int(ctx["gnoise"].shape[0]),
             *kernel_mode(sim.params), tuple(ctx["safe"].shape),
             padded_pair_len(ctx),
-            0 if plan is None else _padded_len(plan.pair_links.shape[0],
-                                               _HEAD_BLOCK),
+            0 if plan is None else _head_len(plan.pair_links.shape[0]),
             ctx["cand_mask"] is not None)
 
 

@@ -500,7 +500,8 @@ class DragonflySimulator:
         ``transfer``, ``device_wait`` and ``fetch`` nest inside
         ``fixed_point``; ``policy`` is the routing policy's decision and
         feedback around a phase (`traffic.run_iteration_engine`, the
-        tenancy round)."""
+        tenancy round); in `traffic.run_iteration_engine` ``decide``
+        and ``publish`` nest inside it."""
         if not self.params.profile_stages:
             return _NO_STAGE
         return _Stage(self, name, self.phase_index if phase is None

@@ -272,7 +272,8 @@ def run_iteration_engine(sim: DragonflySimulator, alloc: Allocation,
     behind (paper §4.3), paying the same §5.1 counter-read overhead.
 
     The decision and the publish are the simulator's ``policy`` stage
-    (``SimParams.profile_stages``), in the phase they decide."""
+    (``SimParams.profile_stages``), in the phase they decide, with the
+    stages ``decide`` and ``publish`` inside it."""
     base_policy = base_policy or RoutingPolicy(RoutingMode.ADAPTIVE_0)
     total_us = 0.0
     lat, st, nmf, wts = [], [], [], []
@@ -280,14 +281,14 @@ def run_iteration_engine(sim: DragonflySimulator, alloc: Allocation,
     nodes = np.asarray(alloc.nodes)
     for (s, d, b) in phases:
         phase = sim.phase_index
-        with sim.stage("policy", phase):
+        with sim.stage("policy", phase), sim.stage("decide", phase):
             batch = DecisionBatch.of(b, site=site, kind=kind)
             modes = engine.decide(batch)      # ONE call for the whole phase
         plan = sim.plan_for(nodes[s], nodes[d], b) if use_plans else None
         res = sim.run_phase(nodes[s], nodes[d], b, base_policy, alloc,
                             modes=modes, plan=plan)
         # post-send counter read (never delays the message itself)
-        with sim.stage("policy", phase):
+        with sim.stage("policy", phase), sim.stage("publish", phase):
             if res.t_us.size == len(batch):
                 engine.bus.publish_flow_arrays(res.latency_us,
                                                res.stalls_per_flit,

@@ -204,9 +204,11 @@ def test_segsum_counts_a_reused_plan_and_a_planless_phase():
     # 300 app + 16 background rows; 5 pair reductions a phase (4 sprays
     # + load_q); 2 link blocks
     (_, plan), reused = delta(lambda: _reused_plan_phases("on", 1))
-    head_blocks = plan.device_bundle["p_sorted"] // 1024
+    p_head = plan.device_bundle["p_sorted"]
+    p_real = int(plan.pair_links.shape[0])
     assert reused == {"sorted_calls": 5, "dense_calls": 1 + 5,
-                      "grid_steps": 2 + 5 * (head_blocks + 2) + 5 * 2}
+                      "grid_steps": 2 + 5 * (p_head // 1024 + 2) + 5 * 2,
+                      "head_pairs": p_real, "head_pad_pairs": p_head - p_real}
 
     src, dst, size = _flows(TWO_BLOCKS, seed=19, n=300)
     sim = DragonflySimulator(
@@ -216,6 +218,7 @@ def test_segsum_counts_a_reused_plan_and_a_planless_phase():
     # the whole pair list dense, padded to 4096-pair buckets: 4k pair
     # blocks x 2 link blocks a reduction
     assert planless["sorted_calls"] == 0
+    assert planless["head_pairs"] == planless["head_pad_pairs"] == 0
     assert planless["dense_calls"] == 1 + 5
     assert planless["grid_steps"] > 2
     assert (planless["grid_steps"] - 2) % (5 * 4 * 2) == 0

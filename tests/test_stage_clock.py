@@ -211,6 +211,29 @@ def test_policy_stage_for_run_iteration_engine(spans):
     assert policy == [0, 0, 1, 1]        # decide, then publish, per phase
 
 
+def test_decide_and_publish_nest_in_the_policy_stage(spans):
+    from repro.dragonfly.traffic import engine_for_arm, run_iteration_engine
+    from repro.policy import AppAwareConfig
+    sim = _sim(True, backend="numpy")
+    alloc = make_allocation(TOPO, 8, spread="inter_groups", seed=1)
+    phases = [(np.arange(8), np.roll(np.arange(8), 1),
+               np.full(8, 65536.0))] * 2
+    engine = engine_for_arm("app_aware", sim, AppAwareConfig(), seed=0)
+    run_iteration_engine(sim, alloc, phases, engine, use_plans=True)
+    st = sim.stage_time_s
+    assert 0 < st["decide"] + st["publish"] <= st["policy"]
+    policy = [(n, kw["phase"]) for n, kw in spans
+              if n in ("df.policy", "df.decide", "df.publish")]
+    assert policy == [("df.policy", 0), ("df.decide", 0),
+                      ("df.policy", 0), ("df.publish", 0),
+                      ("df.policy", 1), ("df.decide", 1),
+                      ("df.policy", 1), ("df.publish", 1)]
+    closed = [n for n in spans.closed if n in ("df.policy", "df.decide",
+                                                "df.publish")]
+    assert closed == ["df.decide", "df.policy", "df.publish",
+                      "df.policy"] * 2            # each inside its policy
+
+
 def test_policy_stage_for_the_tenancy_round(spans):
     from repro.tenancy import InterferenceEngine, TenancyMix, Workload
     mix = TenancyMix("mix", (

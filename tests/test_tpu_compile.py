@@ -20,6 +20,7 @@ from repro.kernels.segment_sum.segment_sum import (
     segment_sum_pallas, segment_sum_sorted_pallas, sorted_grid_steps)
 
 ARIES_LINKS = 56_448
+DFLY_LINKS = 66_048
 
 
 @pytest.fixture(scope="module")
@@ -75,14 +76,18 @@ def test_vmapped_segment_sum_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("lanes,n_head", [
-    (None, 3_760_128),                 # plan-pinned head, 120k flows
-    (None, 15_360),                    # the 512-rank protocol job's head
-    (2, 15_360),                       # the lockstep batch, lane by lane
-], ids=["aries_head", "protocol_head", "vmapped"])
-def test_sorted_segment_sum_compiles_for_v5e(one_chip, lanes, n_head):
-    """The engine hands the sorted kernel the whole pair list (head plus
-    the background bucket) and the plan's scalar-prefetched schedule."""
+@pytest.mark.parametrize("lanes,n_head,n_links", [
+    (None, 3_932_160, ARIES_LINKS),    # plan-pinned head, 120k flows
+    (None, 15_360, ARIES_LINKS),       # the 512-rank protocol job's head
+    (2, 15_360, ARIES_LINKS),          # the lockstep batch, lane by lane
+    (None, 2_621_440, DFLY_LINKS),     # 120k flows, balanced Dragonfly
+    (None, 1_310_720, DFLY_LINKS),     # the 256-rank all-to-all's head
+], ids=["aries_head", "protocol_head", "vmapped", "dfly_head", "a2a_head"])
+def test_sorted_segment_sum_compiles_for_v5e(one_chip, lanes, n_head,
+                                             n_links):
+    """The engine hands the sorted kernel the whole pair list (head,
+    padded by `_head_len`, plus the background bucket) and the plan's
+    scalar-prefetched schedule."""
     n_pairs = n_head + _PAIR_BUCKET_PLAN
     lead = () if lanes is None else (lanes,)
     vals = jax.ShapeDtypeStruct((*lead, n_pairs), jnp.float32,
@@ -90,11 +95,11 @@ def test_sorted_segment_sum_compiles_for_v5e(one_chip, lanes, n_head):
     ids = jax.ShapeDtypeStruct((*lead, n_pairs), jnp.int32,
                                sharding=one_chip)
     sched = jax.ShapeDtypeStruct(
-        (*lead, 3 * sorted_grid_steps(n_head, ARIES_LINKS)), jnp.int32,
+        (*lead, 3 * sorted_grid_steps(n_head, n_links)), jnp.int32,
         sharding=one_chip)
 
     def one(v, i, s):
-        return segment_sum_sorted_pallas(v, i, s, ARIES_LINKS)
+        return segment_sum_sorted_pallas(v, i, s, n_links)
 
     fn = one if lanes is None else jax.vmap(one)
     compiled = jax.jit(fn).lower(vals, ids, sched).compile()
