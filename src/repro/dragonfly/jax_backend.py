@@ -10,7 +10,9 @@ touch the host, and compile time no longer scales with
     for the jax path: the loop-invariant score base (queue-estimate
     gather + hop latency + bias/notification terms) is computed inside
     the graph from the per-link estimate vector, so the expensive
-    [n, ncand, hops] gather runs fused in XLA instead of NumPy.
+    [n, ncand, hops] gather runs on the device instead of in NumPy: on
+    the chip in `path_gather_pallas`, the link table held in VMEM
+    (`uses_path_gather`), elsewhere in XLA.
 
   * **Plan-pinned device buffers.** When a :class:`PhasePlan` is
     replayed, its phase-invariant tensors (``safe``/``valid``/``hops``/
@@ -59,6 +61,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat.runtime import on_tpu, resolve_pallas_kernel
+from repro.kernels.path_gather.path_gather import (
+    LANES, index_rows, path_gather_pallas)
 from repro.kernels.segment_sum.ref import segment_sum_ref
 from repro.kernels.segment_sum.segment_sum import (
     BLOCK, dense_grid_steps, segment_sum_pallas, segment_sum_sorted_pallas,
@@ -87,6 +91,21 @@ TRANSFER = {"h2d_copies": 0, "h2d_bytes": 0, "d2h_bytes": 0}
 #: Nothing counts where the kernel is off.
 SEGSUM = {"sorted_calls": 0, "dense_calls": 0, "grid_steps": 0,
           "head_pairs": 0, "head_pad_pairs": 0}
+
+#: slots of the pipeline's gathers of a per-link vector through the
+#: candidates' link ids ``safe`` (the score's, each feedback
+#: iteration's, and the observables' two), counted always from each
+#: phase's statics and shapes: ``kernel_slots`` where
+#: `path_gather_pallas` does them, ``xla_slots`` where XLA's gather does.
+GATHER = {"kernel_slots": 0, "xla_slots": 0}
+
+#: most links for which the pipeline gathers through ``safe`` with
+#: `path_gather_pallas`: its work grows with the table's rows of 128
+#: links, XLA's gather's does not (`uses_path_gather`).  On a TPU v5e
+#: 5,760,768 ids took the kernel 14.4 / 31.6 / 47.0 / 62.6 ms from
+#: 56,448 / 131,072 / 196,608 / 262,144 links and XLA's gather 42.2 ms,
+#: a crossover near 1,375 table rows; the threshold is 1,280 rows.
+PATH_GATHER_MAX_LINKS = 1_280 * LANES
 
 #: pair-list padding buckets (docs/performance.md).  Plan-reused phases
 #: only redraw the ~bg_flows_per_phase background rows, so their pair
@@ -119,6 +138,14 @@ def kernel_mode(params) -> tuple:
     the parity-testing path."""
     use_kernel = resolve_pallas_kernel(params.pallas_kernel)
     return use_kernel, use_kernel and not on_tpu()
+
+
+def uses_path_gather(use_kernel: bool, n_links: int) -> bool:
+    """Does the pipeline gather through ``safe`` with the Pallas kernel?
+    Where the segment sum's kernel runs and the link table is at most
+    `PATH_GATHER_MAX_LINKS`.  The pipeline then takes ``safe`` as its
+    [rows, 128] `path_view`, else as the [n, ncand, hops] ids."""
+    return use_kernel and n_links <= PATH_GATHER_MAX_LINKS
 
 
 def _padded_len(n: int, bucket: int) -> int:
@@ -167,7 +194,21 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     background sliver), the NIC sum and planless pair lists
     (``p_sorted == 0``) scatter-add: the dense kernel, or
     `segment_sum`.
+
+    ``safe`` is the candidates' [n, ncand, hops] link ids, or their
+    [rows, 128] `path_view` where `uses_path_gather` holds: the four
+    gathers of a per-link vector through them (`link_gather`) then run
+    in `path_gather_pallas`, with the link table in VMEM.
     """
+    def link_gather(table):
+        """``table[safe]``, [n, ncand, hops]."""
+        with jax.named_scope("path_gather"):
+            if safe.ndim == validf.ndim:
+                return table[safe]
+            return from_path_view(
+                path_gather_pallas(table, safe, interpret=interpret),
+                validf.shape)
+
     def seg_sum(vals, ids):
         with jax.named_scope("segsum"):
             if use_kernel:
@@ -204,7 +245,7 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     # loop-invariant score base, in-graph (the hoisted scorer of the
     # numpy fast path: estimate gather + hop latency + bias terms)
     with jax.named_scope("score"):
-        base = (est_queue_s[safe] * validf).sum(axis=-1) \
+        base = (link_gather(est_queue_s) * validf).sum(axis=-1) \
             + hl_rows[:, None] * hops
         score0 = base + jnp.where(is_nonmin[None, :], bias_rows[:, None],
                                   0.0)
@@ -244,7 +285,7 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
         w, load_i = carry
         rho_fb = load_i / cap_window
         extra = jnp.maximum(0.0, rho_fb - feedback_rho0) * window_s
-        score = score0 + (extra[safe] * validf).sum(axis=-1)
+        score = score0 + (link_gather(extra) * validf).sum(axis=-1)
         w = 0.5 * (w + spray(score, g))
         return (w, loads(w)), None
 
@@ -262,10 +303,10 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     # --- observables: per-flow (L_us, s) ------------------------------
     with jax.named_scope("observables"):
         rho = load_i / cap_window
-        rho_path = rho[safe] * validf                   # [n, ncand, hops]
+        rho_path = link_gather(rho) * validf        # [n, ncand, hops]
         excess = jnp.maximum(0.0, rho_path - rho_threshold)
         qdelay_ns = queue_delay_ns * excess.sum(axis=-1)
-        qwait_ns = (link_queue_s[safe] * validf).sum(axis=-1) \
+        qwait_ns = (link_gather(link_queue_s) * validf).sum(axis=-1) \
             * qwait_fraction * 1e9
         lat_ns_cand = 2.0 * nic_latency_ns + hops * hop_latency_ns \
             + qdelay_ns + qwait_ns
@@ -369,18 +410,61 @@ def _device_plan(plan, n_links: int) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _tail_writer(n_app: int, p_head: int):
+def _tail_writer(n_app: int, p_head: int, view: bool):
     """Jitted donated-buffer tail update: writes the per-phase background
     rows/pairs into the pinned full-size buffers IN PLACE (the buffers
     are donated, so XLA aliases them instead of copying the plan-pinned
-    head every phase)."""
+    head every phase).  With ``view`` the first buffer is ``safe``'s
+    `path_view`, and the tail's ids go to their places there."""
     def write(bufs, tails):
+        safe = (_write_view(bufs[0], tails[0], n_app) if view
+                else bufs[0].at[n_app:].set(tails[0]))
         rows = tuple(b.at[n_app:].set(t)
-                     for b, t in zip(bufs[:4], tails[:4]))
+                     for b, t in zip(bufs[1:4], tails[1:4]))
         pairs = tuple(b.at[p_head:].set(t)
                       for b, t in zip(bufs[4:], tails[4:]))
-        return rows + pairs
+        return (safe, *rows, *pairs)
     return jax.jit(write, donate_argnums=(0,))
+
+
+def path_view(safe):
+    """The candidates' link ids [n, ncand, hops] as the [rows, 128] int32
+    view `path_gather_pallas` reads (`index_rows` rows, zero-padded), in
+    the order of their n-minor tiles: row ``(c * nb + b) * hops + h``
+    holds the ids of rows ``b * 128`` to ``b * 128 + 127`` at candidate
+    ``c``, hop ``h``.  XLA on a TPU lays the pipeline's [n, ncand, 8]
+    arrays out n-minor in (8, 128) tiles, so the gathered view's tiles
+    are theirs and `from_path_view` costs no relayout."""
+    n, nc, nh = safe.shape
+    nb = -(-n // LANES)
+    ids = jnp.pad(safe.astype(jnp.int32), ((0, nb * LANES - n), (0, 0),
+                                          (0, 0)))
+    ids = ids.reshape(nb, LANES, nc, nh).transpose(2, 0, 3, 1)
+    ids = ids.reshape(-1, LANES)
+    return jnp.pad(ids, ((0, index_rows(ids.size) - ids.shape[0]), (0, 0)))
+
+
+def from_path_view(out, shape):
+    """The [n, ncand, hops] array of ``shape`` whose `path_view` is
+    ``out``."""
+    n, nc, nh = shape
+    nb = -(-n // LANES)
+    out = out[:nc * nb * nh].reshape(nc, nb, nh, LANES)
+    return out.transpose(1, 3, 0, 2).reshape(nb * LANES, nc, nh)[:n]
+
+
+def _write_view(view, tail, n_app: int):
+    """``view`` (a `path_view` of ``n_app + len(tail)`` rows of ids) with
+    ``tail``'s ids written as its rows ``n_app`` on."""
+    n_bg, nc, nh = tail.shape
+    nb = -(-(n_app + n_bg) // LANES)
+    r = n_app + np.arange(n_bg)[:, None, None]
+    rows = (np.arange(nc)[:, None] * nb + r // LANES) * nh + np.arange(nh)
+    lanes = np.broadcast_to(r % LANES, rows.shape)
+    return view.at[rows, lanes].set(tail)
+
+
+_path_view = jax.jit(path_view)
 
 
 def _pad_pairs(links: np.ndarray, fc: np.ndarray, pad_to: int):
@@ -421,6 +505,8 @@ def _prepare_inputs(sim, ctx: dict):
     plan = ctx["plan"]
     n_app = ctx["n_app"]
 
+    mode = kernel_mode(p)
+    view = uses_path_gather(mode[0], int(tp.n_links))
     if plan is not None:
         dev = _device_plan(plan, int(tp.n_links))
         seg_off, sched = dev["seg_off"], dev["sched"]
@@ -437,28 +523,37 @@ def _prepare_inputs(sim, ctx: dict):
                      _f32(ctx["hops"][sl]), _i32(ctx["nic_ids"][sl]),
                      bl, bf, bm)
             bufs = dev.get("bufs")
-            if (bufs is not None and bufs[0].shape[0] == n_all
-                    and bufs[4].shape[0] == p_sorted + bl.shape[0]):
+            if (bufs is not None and bufs[1].shape[0] == n_all
+                    and bufs[4].shape[0] == p_sorted + bl.shape[0]
+                    and (bufs[0].ndim == 2) == view):
                 # steady state: write ONLY the tails into the donated
                 # full-size buffers — the pinned head is never re-copied
                 dev["bufs"] = None       # donation consumes the olds
-                bufs = _tail_writer(n_app, p_sorted)(bufs, tails)
+                bufs = _tail_writer(n_app, p_sorted, view)(bufs, tails)
             else:
                 bufs = tuple(
                     jnp.concatenate([head, tail]) for head, tail in zip(
                         (dev["safe"], dev["validf"], dev["hops"],
                          dev["nic_ids"], dev["pair_links"],
                          dev["pair_fc"], dev["pair_mask"]), tails))
+                if view:
+                    bufs = (_path_view(bufs[0]), *bufs[1:])
             dev["bufs"] = bufs
             (safe, validf, hops, nic_ids,
              pair_links, pair_fc, pair_mask) = bufs
         else:
             safe, validf = dev["safe"], dev["validf"]
+            if view:
+                if "safe_view" not in dev:
+                    dev["safe_view"] = _path_view(safe)
+                safe = dev["safe_view"]
             hops, nic_ids = dev["hops"], dev["nic_ids"]
             pair_links, pair_fc = dev["pair_links"], dev["pair_fc"]
             pair_mask = dev["pair_mask"]
     else:
         safe = _i32(ctx["safe"])
+        if view:
+            safe = _path_view(safe)
         validf = _f32(ctx["valid"])
         hops = _f32(ctx["hops"])
         nic_ids = _i32(ctx["nic_ids"])
@@ -485,9 +580,12 @@ def _prepare_inputs(sim, ctx: dict):
         _f32(p.qwait_fraction), _f32(p.stall_gain),
         _f32(tp.nic_latency_ns), _f32(tp.hop_latency_ns),
     )
-    statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links),
-               *kernel_mode(p), p_sorted)
-    if statics[2]:
+    statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links), *mode,
+               p_sorted)
+    # the score's and the observables' two, one a feedback iteration
+    GATHER["kernel_slots" if view else "xla_slots"] += \
+        (3 + statics[0] - 1) * int(validf.size)
+    if mode[0]:
         _count_segsum(statics, int(pair_links.shape[0]),
                       int(nic_ids.shape[0]),
                       0 if plan is None else int(plan.pair_links.shape[0]))

@@ -31,9 +31,7 @@ whole (shorter) axis of the dense kernel; tests/test_tpu_compile.py
 compiles the engine's real widths for a described v5e.
 
 Under ``jax.vmap`` the lanes go through a kernel one after another
-(``lax.map``): batching the ``pallas_call`` itself would give each 1-D
-block a squeezed batch dim, and Mosaic requires the last two block dims
-to tile by (8, 128) or span the array.
+(``repro.kernels.lanes.by_lane``).
 """
 
 from __future__ import annotations
@@ -46,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.lanes import by_lane
 
 #: block width of the sorted kernel and of its schedule
 BLOCK = 1024
@@ -67,19 +67,6 @@ def _accumulate(ids_ref, val_ref, o_ref, seg_base):
         jnp.int32, (ids.shape[0], o_ref.shape[0]), 1)
     hit = ids[:, None] == seg             # [block_pairs, block_segs]
     o_ref[...] += jnp.sum(jnp.where(hit, vals[:, None], 0.0), axis=0)
-
-
-def _by_lane(one):
-    """``one`` as a function whose vmap runs it lane by lane."""
-    fn = jax.custom_batching.custom_vmap(one)
-
-    @fn.def_vmap
-    def _lanes(axis_size, in_batched, *args):
-        args = tuple(x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
-                     for x, b in zip(args, in_batched))
-        return jax.lax.map(lambda a: one(*a), args), True
-
-    return fn
 
 
 # ------------------------------------------------------------------ dense
@@ -114,7 +101,7 @@ def segment_sum_pallas(values, segment_ids, num_segments: int, *,
                        interpret: bool = False):
     """values: [n] float; segment_ids: [n] int, any order ->
     [num_segments] float32."""
-    return _by_lane(functools.partial(
+    return by_lane(functools.partial(
         _segment_sum_1d, num_segments=num_segments, block_pairs=block_pairs,
         block_segs=block_segs, interpret=interpret))(values, segment_ids)
 
@@ -203,7 +190,7 @@ def segment_sum_sorted_pallas(values, segment_ids, schedule,
     the schedule covers; schedule: `sorted_schedule` of their offsets ->
     [num_segments] float32.  Pairs past the schedule's last pair block
     are not read."""
-    return _by_lane(functools.partial(
+    return by_lane(functools.partial(
         _sorted_1d, num_segments=num_segments, interpret=interpret))(
             values, segment_ids, schedule)
 

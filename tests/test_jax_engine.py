@@ -228,6 +228,64 @@ def test_segsum_counts_a_reused_plan_and_a_planless_phase():
     assert jax_backend.SEGSUM == off_before
 
 
+@pytest.mark.parametrize("n,n_app", [(300, 284), (1_000, 990), (129, 1)],
+                         ids=["one_block", "straddles_blocks", "edge"])
+def test_path_view_round_trips_and_takes_a_tail(n, n_app):
+    """`path_view` and `from_path_view` are inverse on [n, ncand, hops]
+    ids, and `_write_view` puts a tail's ids where `path_view` of the
+    whole would hold them."""
+    import jax.numpy as jnp
+
+    from repro.dragonfly.jax_backend import (
+        _write_view, from_path_view, path_view)
+
+    rng = np.random.default_rng(3)
+    ids = rng.integers(0, 5_000, (n, 6, 8)).astype(np.int32)
+    view = path_view(jnp.asarray(ids))
+    assert view.shape[1] == 128
+    np.testing.assert_array_equal(
+        np.asarray(from_path_view(view, ids.shape)), ids)
+    stale = ids.copy()
+    stale[n_app:] = 0
+    written = _write_view(path_view(jnp.asarray(stale)),
+                          jnp.asarray(ids[n_app:]), n_app)
+    np.testing.assert_array_equal(np.asarray(written), np.asarray(view))
+
+
+def test_path_gather_agrees_with_xla_gather_on_a_reused_plan(monkeypatch):
+    """With the kernels on, two plan-reused phases through the Pallas
+    path gather (the second writing its background tail into the donated
+    index view) give the kernel outputs (w, rho, load_q, lat_us, s_flit)
+    of XLA's gather through the same ids; `GATHER` counts the slots."""
+    from repro.dragonfly import jax_backend
+
+    real = jax_backend.fixed_point_jax
+
+    def phases(max_links):
+        monkeypatch.setattr(jax_backend, "PATH_GATHER_MAX_LINKS", max_links)
+        seen = []
+
+        def spy(sim, ctx):
+            seen.append(real(sim, ctx))
+            return seen[-1]
+
+        monkeypatch.setattr(jax_backend, "fixed_point_jax", spy)
+        before = dict(jax_backend.GATHER)
+        _, plan = _reused_plan_phases("on")
+        n_slots = plan.safe.size + 16 * plan.safe[0].size     # + background
+        return seen, {k: v - before[k]
+                      for k, v in jax_backend.GATHER.items()}, n_slots
+
+    kern, kern_count, n_slots = phases(TWO_BLOCKS.n_links)
+    xla, xla_count, _ = phases(TWO_BLOCKS.n_links - 1)
+    assert kern_count == {"kernel_slots": 2 * 6 * n_slots, "xla_slots": 0}
+    assert xla_count == {"kernel_slots": 0, "xla_slots": 2 * 6 * n_slots}
+    assert len(kern) == len(xla) == 2
+    for k_out, x_out in zip(kern, xla):
+        for a, b in zip(k_out, x_out):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-9)
+
+
 def test_pallas_kernel_auto_is_off_on_cpu():
     from repro.compat.runtime import on_tpu, resolve_pallas_kernel
     if not on_tpu():
@@ -246,11 +304,11 @@ def test_pallas_kernel_knob_validated():
 # --------------------------------------------------------------------------
 # Batched dispatch: run_phase_batch == per-sim run_phase.
 # --------------------------------------------------------------------------
-def _batch_calls(backend, n_sims=3, seed0=20):
+def _batch_calls(backend, n_sims=3, seed0=20, knob="auto"):
     calls = []
     for k in range(n_sims):
-        sim = DragonflySimulator(TOPO, SimParams(seed=seed0 + k,
-                                                 backend=backend))
+        sim = DragonflySimulator(TOPO, SimParams(
+            seed=seed0 + k, backend=backend, pallas_kernel=knob))
         src, dst, size = _flows(TOPO, seed=seed0 + k)
         calls.append((sim, dict(src_nodes=src, dst_nodes=dst, bytes_=size,
                                 policy=RoutingPolicy(
@@ -269,6 +327,20 @@ def test_run_phase_batch_matches_sequential(backend):
         assert np.array_equal(rb.t_us, rs.t_us)
         assert np.array_equal(rb.latency_us, rs.latency_us)
         assert np.array_equal(rb.flits, rs.flits)
+
+
+def test_run_phase_batch_with_the_kernels_matches_sequential():
+    """The vmapped dispatch runs the segment sums and the path gather
+    lane by lane, each lane through its own path view and tables."""
+    from repro.dragonfly import jax_backend
+    before = dict(jax_backend.GATHER)
+    batched = run_phase_batch([(sim, dict(kw)) for sim, kw in
+                               _batch_calls("jax", knob="on")])
+    assert jax_backend.GATHER["kernel_slots"] > before["kernel_slots"]
+    sequential = [sim.run_phase(**kw)
+                  for sim, kw in _batch_calls("jax", knob="on")]
+    for rb, rs in zip(batched, sequential):
+        _assert_close(rb, rs, rtol=1e-4)
 
 
 def test_run_phase_batch_uses_one_vmapped_dispatch():

@@ -266,3 +266,78 @@ def test_sorted_segment_sum_vmaps_lanes_with_their_own_schedules():
         jnp.asarray(vals), jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# path_gather: a per-link vector read at every hop of every candidate
+# path, the link table held in VMEM.
+# --------------------------------------------------------------------------
+def _index_view(ids):
+    """Flat ``ids`` zero-padded to `index_rows` rows of 128."""
+    from repro.kernels.path_gather import index_rows
+
+    rows = index_rows(ids.size)
+    return jnp.asarray(np.pad(ids, (0, rows * 128 - ids.size)).reshape(
+        rows, 128))
+
+
+@pytest.mark.parametrize("n_links,n_ids", [
+    (56_448, 4_096),             # Aries' link ids
+    (66_048, 4_096),             # the balanced Dragonfly's link ids
+    (300, 4_096),                # a ragged table
+    (128, 4_096),                # one row of 128
+    (56_448, 120_016 * 48),      # 120k-flow phase: rows x candidates x hops
+    (66_048, 65_296 * 48),       # the 256-rank all-to-all's phase
+    (1_400, 1_024 * 128),        # a tail-sized 1,024 rows of ids
+], ids=["aries", "dfly", "ragged", "one_row", "m2m_ids", "a2a_ids",
+        "rows_1024"])
+def test_path_gather_is_the_table_at_the_ids(n_links, n_ids):
+    from repro.kernels.path_gather import path_gather_pallas
+
+    table = RNG.standard_normal(n_links).astype(np.float32)
+    ids = RNG.integers(0, n_links, n_ids).astype(np.int32)
+    ids[:2] = 0, n_links - 1
+    view = _index_view(ids)
+    out = np.asarray(path_gather_pallas(jnp.asarray(table), view,
+                                        interpret=True))
+    assert out.shape == view.shape
+    np.testing.assert_array_equal(out.reshape(-1)[:n_ids], table[ids])
+
+
+def test_path_gather_pads_rows_off_its_block():
+    """[m, 128] ids whose m is a multiple of neither block come back as
+    [m, 128]."""
+    from repro.kernels.path_gather import path_gather_pallas
+    from repro.kernels.path_gather.path_gather import BLOCK_ROWS, SUB_ROWS
+
+    m = BLOCK_ROWS + SUB_ROWS + 5
+    table = RNG.standard_normal(3_000).astype(np.float32)
+    ids = RNG.integers(0, 3_000, (m, 128)).astype(np.int32)
+    out = path_gather_pallas(jnp.asarray(table), jnp.asarray(ids),
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), table[ids])
+
+
+def test_path_gather_vmaps_lane_by_lane():
+    """The lockstep batch: each lane gathers from its own table through
+    its own ids, or through ids all lanes share."""
+    import jax
+
+    from repro.kernels.path_gather import path_gather_pallas
+
+    tables = RNG.standard_normal((3, 1_000)).astype(np.float32)
+    ids = RNG.integers(0, 1_000, (3, 700)).astype(np.int32)
+    views = jnp.stack([_index_view(i) for i in ids])
+
+    def kern(t, v):
+        return path_gather_pallas(t, v, interpret=True)
+
+    out = np.asarray(jax.vmap(kern)(jnp.asarray(tables), views))
+    for lane in range(3):
+        np.testing.assert_array_equal(out[lane].reshape(-1)[:700],
+                                      tables[lane][ids[lane]])
+    shared = np.asarray(jax.vmap(kern, in_axes=(0, None))(
+        jnp.asarray(tables), views[0]))
+    for lane in range(3):
+        np.testing.assert_array_equal(shared[lane].reshape(-1)[:700],
+                                      tables[lane][ids[0]])

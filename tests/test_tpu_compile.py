@@ -1,13 +1,15 @@
-"""The Pallas segment sums compile for a TPU v5e at the engine's widths.
+"""The Pallas segment sums and path gather compile for a TPU v5e at the
+engine's widths.
 
 Interpret-mode tests (tests/test_kernels.py) cannot see what Mosaic
 refuses: a 1-D block whose tiling differs from XLA's T(1024) layout of
-the operand, or a batched 1-D block.  These tests compile the kernel,
+the operand, or a batched 1-D block.  These tests compile the kernels,
 not interpreted, for one chip of a described (not attached) ``v5e:2x2``
 at the real widths of the jax phase engine on the default Aries machine
-(56,448 links).  The topology is described inside a fixture, never at
-import time, so only the worker that runs this file loads the TPU
-compiler; where it cannot be described the tests skip.
+(56,448 links) and on the balanced Dragonfly (66,048).  The topology is
+described inside a fixture, never at import time, so only the worker
+that runs this file loads the TPU compiler; where it cannot be
+described the tests skip.
 """
 
 import jax
@@ -103,4 +105,29 @@ def test_sorted_segment_sum_compiles_for_v5e(one_chip, lanes, n_head,
 
     fn = one if lanes is None else jax.vmap(one)
     compiled = jax.jit(fn).lower(vals, ids, sched).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes,n_links,n_rows", [
+    (None, ARIES_LINKS, 120_016),    # 120k-flow phase on Aries
+    (None, DFLY_LINKS, 120_016),     # 120k flows, balanced Dragonfly
+    (None, DFLY_LINKS, 65_296),      # the 256-rank all-to-all's phase
+    (None, ARIES_LINKS, 464),        # the 512-rank protocol job's phase
+    (2, ARIES_LINKS, 464),           # the lockstep batch, lane by lane
+], ids=["aries_m2m", "dfly_m2m", "dfly_a2a", "protocol", "vmapped"])
+def test_path_gather_compiles_for_v5e(one_chip, lanes, n_links, n_rows):
+    """The engine hands the path gather a per-link table and the
+    candidates' [rows, 6, 8] link ids as their `path_view`."""
+    from repro.dragonfly.jax_backend import path_view
+    from repro.kernels.path_gather import path_gather_pallas
+
+    lead = () if lanes is None else (lanes,)
+    view = jax.eval_shape(path_view, jax.ShapeDtypeStruct(
+        (n_rows, 6, 8), jnp.int32)).shape
+    table = jax.ShapeDtypeStruct((*lead, n_links), jnp.float32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((*lead, *view), jnp.int32, sharding=one_chip)
+    fn = path_gather_pallas if lanes is None else jax.vmap(
+        path_gather_pallas)
+    compiled = jax.jit(fn).lower(table, ids).compile()
     assert "tpu_custom_call" in compiled.as_text()
