@@ -158,7 +158,7 @@ def run_main(topo, n_flows: int, *, seed: int, steady: int) -> dict:
                      "stages_s": run["stages_s"],
                      **parity(run["jax"], run["numpy"])}
     plan = run["plan"]
-    out["rows"] = int(plan.device_bundle["bufs"][0].shape[0])
+    out["rows"] = int(plan.device_bundle.bufs[0].shape[0])
     out["pairs"] = int(plan.pair_links.shape[0])
     return out
 

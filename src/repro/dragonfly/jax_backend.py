@@ -15,23 +15,23 @@ touch the host, and compile time no longer scales with
     (`uses_path_gather`), elsewhere in XLA.
 
   * **Plan-pinned device buffers.** When a :class:`PhasePlan` is
-    replayed, its phase-invariant tensors (``safe``/``valid``/``hops``/
-    ``pair_links``/``pair_fc``/``nic_ids``) are transferred once and
-    pinned on the plan (``plan.device_bundle``); the plan cache key
-    already covers topology spec + fault epoch + notify epoch, so a
-    stale bundle cannot outlive its plan.  Per phase only the small
-    per-link state, the background-flow slivers, and the Gumbel noise
-    block move host->device.  The noise block is not donated: no
-    output has its [iters, n, ncand] shape, so XLA could never reuse
-    its buffer (a TPU v5e reports such a donation as unusable).
+    replayed, its phase-invariant tensors are transferred once and
+    pinned on the plan as a `_DevicePlan` (``plan.device_bundle``),
+    which also holds the full-size buffers of the plan's last phase;
+    the plan cache key already covers topology spec + fault epoch +
+    notify epoch, so a stale bundle cannot outlive its plan.  Per phase
+    only the small per-link state, the background-flow slivers (written
+    into the donated buffers in place) and the Gumbel noise block move
+    host->device.  The noise block is not donated: no output has its
+    [iters, n, ncand] shape, so XLA could never reuse its buffer (a TPU
+    v5e reports such a donation as unusable).
 
-  * **Stable shapes.** Background flows redraw candidates per phase,
-    which used to change the (link, flow-cand) pair-list length P every
-    phase and force a full recompile EVERY phase (the 2.64s
-    ``fixed_point`` stage of the v1 bench was almost entirely XLA
-    retracing).  Pair lists are now padded to coarse buckets with
+  * **Stable shapes.** Background flows redraw candidates per phase, so
+    the (link, flow-cand) pair-list length P changes every phase.  One
+    rule, `_pair_layout`, pads pair lists to coarse buckets with
     zero-weight entries (mask 0.0, link 0 — exact no-ops under the
-    segment sum), so steady-state phases reuse one compiled executable.
+    segment sum), and `batch_signature` keys on it too, so steady-state
+    phases reuse one compiled executable.
 
 Fault candidate masks and congestion-notification penalties are both
 consumed in-graph (the mask as a ``where(+inf)`` before every softmin,
@@ -53,7 +53,9 @@ like the NumPy backend and matches it within float32 tolerance
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import NamedTuple
 
 import numpy as np
 
@@ -172,13 +174,13 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
                     nic_ids, pair_links, pair_fc, pair_mask, seg_off,
                     sched, window_s, feedback_rho0, rho_threshold,
                     queue_delay_ns, qwait_fraction, stall_gain,
-                    nic_latency_ns, hop_latency_ns, *, n_spray: int,
-                    n_links: int, use_kernel: bool, interpret: bool,
-                    p_sorted: int):
+                    nic_latency_ns, hop_latency_ns, *, n_links: int,
+                    use_kernel: bool, interpret: bool, p_sorted: int):
     """One phase: score -> spray -> lax.scan feedback -> observables.
 
     Pure in its arguments; statics select the segment-sum implementation
-    (Pallas vs jax.ops.segment_sum) and fix loop count / bin count.
+    (Pallas vs jax.ops.segment_sum) and fix the bin count; the loop
+    count is ``gnoise``'s leading dimension.
     ``cand_mask`` may be None (healthy machine) — the mask branch then
     never enters the graph.  ``pair_mask`` zeroes the bucket-padding
     entries so they are exact no-ops in every accumulation.
@@ -294,7 +296,6 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     # gnoise[it]; compile time still does not scale with n_spray
     with jax.named_scope("feedback"):
         (w, load_i), _ = jax.lax.scan(body, (w0, loads(w0)), gnoise[1:])
-    del n_spray                           # loop count lives in the shape
 
     with jax.named_scope("loads"):
         load_q = pair_sum((size_all[:, None] * w).reshape(-1)[pair_fc]
@@ -318,30 +319,24 @@ def _phase_pipeline(safe, validf, hops, is_nonmin, cand_mask, est_queue_s,
     return w, rho, load_q, lat_us, s_flit
 
 
-#: positional index of cand_mask in _phase_pipeline's signature
-_MASK_ARG = 4
-_N_ARGS = 30
+class _Statics(NamedTuple):
+    """`_phase_pipeline`'s static arguments for one phase."""
+    n_links: int
+    use_kernel: bool
+    interpret: bool
+    p_sorted: int
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_pipeline(n_spray: int, n_links: int, use_kernel: bool,
-                     interpret: bool, p_sorted: int, batched: bool,
-                     has_mask: bool):
-    """Compiled pipeline per (statics, batched, mask-presence) combo.
+def _jitted_pipeline(statics: _Statics, batched: bool):
+    """Compiled pipeline per (statics, batched) combo.
 
     ``batched`` wraps the core in ``jax.vmap`` over a stacked leading
-    phase axis — scalars ride along as [B] vectors.
+    phase axis — scalars ride along as [B] vectors, and an absent
+    ``cand_mask`` (None) is an empty pytree.
     """
-    core = functools.partial(_phase_pipeline, n_spray=n_spray,
-                             n_links=n_links, use_kernel=use_kernel,
-                             interpret=interpret, p_sorted=p_sorted)
-    fn = core
-    if batched:
-        axes = [0] * _N_ARGS
-        if not has_mask:
-            axes[_MASK_ARG] = None      # cand_mask=None: empty pytree
-        fn = jax.vmap(core, in_axes=tuple(axes))
-    return jax.jit(fn)
+    core = functools.partial(_phase_pipeline, **statics._asdict())
+    return jax.jit(jax.vmap(core) if batched else core)
 
 
 # ------------------------------------------------------- input preparation
@@ -362,18 +357,60 @@ def _i32(a):
     return _put(a, jnp.int32)
 
 
-def _device_plan(plan, n_links: int) -> dict:
-    """Pin a PhasePlan's phase-invariant tensors on device (once).
+@dataclasses.dataclass(eq=False)
+class _DevicePlan:
+    """A PhasePlan's tensors pinned on the device (`_device_plan`): the
+    heads (``safe`` to ``pair_mask``, the pairs link-sorted and padded to
+    ``p_sorted``), the sorted head's ``seg_off`` and ``sched``; and
+    ``bufs``, the last phase's `buffers`, None while they are donated."""
+    safe: jax.Array
+    validf: jax.Array
+    hops: jax.Array
+    nic_ids: jax.Array
+    pair_links: jax.Array
+    pair_fc: jax.Array
+    pair_mask: jax.Array
+    seg_off: jax.Array
+    sched: jax.Array
+    p_sorted: int
+    bufs: tuple | None = None
 
-    Stored ON the plan (``plan.device_bundle``) so the bundle's lifetime
-    is exactly the plan's; `plan_for`'s cache key already covers the
-    topology spec and the fault/notify epochs, which is what keys the
-    device side of the cache too.
+    def buffers(self, tails: tuple, view: bool) -> tuple:
+        """A phase's (safe, validf, hops, nic_ids, pair_links, pair_fc,
+        pair_mask): the heads followed by ``tails`` (its background rows
+        and padded pairs; none without background rows), ``safe`` as its
+        `path_view` with ``view``.  Where the last phase's buffers agree
+        in rows, pairs and view-ness, only the tails are written into
+        them, donated: the pinned heads are never re-copied."""
+        n_app = self.validf.shape[0]
+        n_rows = n_app + (tails[0].shape[0] if tails else 0)
+        n_pairs = self.p_sorted + (tails[4].shape[0] if tails else 0)
+        heads = (self.safe, self.validf, self.hops, self.nic_ids,
+                 self.pair_links, self.pair_fc, self.pair_mask)
+        bufs = self.bufs
+        if (bufs is not None and bufs[1].shape[0] == n_rows
+                and bufs[4].shape[0] == n_pairs
+                and (bufs[0].ndim == 2) == view):
+            if tails:
+                self.bufs = None         # donation consumes the olds
+                bufs = _tail_writer(n_app, self.p_sorted, view)(bufs, tails)
+        else:
+            bufs = tuple(jnp.concatenate([h, t])
+                         for h, t in zip(heads, tails)) if tails else heads
+            if view:
+                bufs = (_path_view(bufs[0]), *bufs[1:])
+        self.bufs = bufs
+        return bufs
+
+
+def _device_plan(plan, n_links: int) -> _DevicePlan:
+    """Pin a PhasePlan's phase-invariant tensors on device, once, as
+    ``plan.device_bundle``: the bundle lives exactly as long as its plan.
 
     The pair list is pinned SORTED BY LINK ID (a host-side argsort, paid
-    once per plan), padded to `_head_len` with zero-mask entries on the
-    last link (sort order survives, padded values are exactly 0.0),
-    with its segment offsets alongside and the sorted
+    once per plan), padded to its `_pair_layout` head with zero-mask
+    entries on the last link (sort order survives, padded values are
+    exactly 0.0), with its segment offsets alongside and the sorted
     kernel's visit list (`sorted_schedule`) built from them on the host:
     both of the pipeline's head reductions, the Pallas kernel's and the
     blocked cumsum-diff, need sorted block-aligned segments.  The plan's
@@ -384,7 +421,7 @@ def _device_plan(plan, n_links: int) -> dict:
     if dev is None:
         pl = np.asarray(plan.pair_links)
         order = np.argsort(pl, kind="stable")
-        p_pad = _head_len(pl.shape[0])
+        p_pad, _ = _pair_layout(plan, pl.shape[0])
         links = np.full(p_pad, n_links - 1, dtype=np.int32)
         links[:pl.shape[0]] = pl[order]
         fc = np.zeros(p_pad, dtype=np.int32)
@@ -393,18 +430,17 @@ def _device_plan(plan, n_links: int) -> dict:
         mask[:pl.shape[0]] = 1.0
         off = np.zeros(n_links + 1, dtype=np.int64)
         np.cumsum(np.bincount(links, minlength=n_links), out=off[1:])
-        dev = {
-            "safe": jnp.asarray(plan.safe, dtype=jnp.int32),
-            "validf": jnp.asarray(plan.valid, dtype=jnp.float32),
-            "hops": jnp.asarray(plan.hops, dtype=jnp.float32),
-            "nic_ids": jnp.asarray(plan.nic_ids, dtype=jnp.int32),
-            "pair_links": jnp.asarray(links),
-            "pair_fc": jnp.asarray(fc),
-            "pair_mask": jnp.asarray(mask),
-            "seg_off": jnp.asarray(off, dtype=jnp.int32),
-            "sched": jnp.asarray(sorted_schedule(off)),
-            "p_sorted": p_pad,
-        }
+        dev = _DevicePlan(
+            safe=jnp.asarray(plan.safe, dtype=jnp.int32),
+            validf=jnp.asarray(plan.valid, dtype=jnp.float32),
+            hops=jnp.asarray(plan.hops, dtype=jnp.float32),
+            nic_ids=jnp.asarray(plan.nic_ids, dtype=jnp.int32),
+            pair_links=jnp.asarray(links),
+            pair_fc=jnp.asarray(fc),
+            pair_mask=jnp.asarray(mask),
+            seg_off=jnp.asarray(off, dtype=jnp.int32),
+            sched=jnp.asarray(sorted_schedule(off)),
+            p_sorted=p_pad)
         plan.device_bundle = dev
     return dev
 
@@ -483,73 +519,45 @@ def _pad_pairs(links: np.ndarray, fc: np.ndarray, pad_to: int):
     return _put(pl), _put(pf), _put(pm)
 
 
-def padded_pair_len(ctx: dict) -> int:
-    """Total pair-list length AFTER bucket padding (shape-signature
-    component: phases agreeing here share one compiled executable)."""
-    P = int(ctx["pair_links"].shape[0])
-    plan = ctx["plan"]
-    if plan is not None:
-        p_app = int(plan.pair_links.shape[0])
-        head = _head_len(p_app)
-        n_bg = P - p_app
-        if n_bg == 0:
-            return head
-        return head + _padded_len(n_bg, _PAIR_BUCKET_PLAN)
-    return _padded_len(P, _PAIR_BUCKET_FULL)
+def _pair_layout(plan, n_pairs: int) -> tuple[int, int]:
+    """(head, tail): the padded lengths of a phase's link-sorted pair
+    head and its unsorted tail, from its ``plan`` (None: planless) and
+    its ``n_pairs`` (link, flow-cand) pairs.
+
+    A plan's own pairs form the head, padded by `_head_len`; the rest,
+    its background flows' pairs, pad to `_PAIR_BUCKET_PLAN` buckets
+    (none where there are none).  A planless phase has no head and pads
+    all its pairs to `_PAIR_BUCKET_FULL` buckets."""
+    if plan is None:
+        return 0, _padded_len(n_pairs, _PAIR_BUCKET_FULL)
+    p_app = int(plan.pair_links.shape[0])
+    n_bg = n_pairs - p_app
+    return (_head_len(p_app),
+            _padded_len(n_bg, _PAIR_BUCKET_PLAN) if n_bg else 0)
 
 
 def _prepare_inputs(sim, ctx: dict):
-    """ctx (from `_phase_begin`) -> (pipeline inputs, statics)."""
+    """ctx (from `_phase_begin`) -> (pipeline inputs, `_Statics`)."""
     p = sim.params
     tp = sim.topo
     plan = ctx["plan"]
-    n_app = ctx["n_app"]
-
-    mode = kernel_mode(p)
-    view = uses_path_gather(mode[0], int(tp.n_links))
+    n_links = int(tp.n_links)
+    use_kernel, interpret = kernel_mode(p)
+    view = uses_path_gather(use_kernel, n_links)
+    head, tail = _pair_layout(plan, ctx["pair_links"].shape[0])
     if plan is not None:
-        dev = _device_plan(plan, int(tp.n_links))
-        seg_off, sched = dev["seg_off"], dev["sched"]
-        p_sorted = dev["p_sorted"]
-        n_all = ctx["safe"].shape[0]
-        if n_all > n_app:               # background rows ride along
-            sl = slice(n_app, None)
+        dev = _device_plan(plan, n_links)
+        tails = ()
+        if ctx["safe"].shape[0] > ctx["n_app"]:   # background rows ride along
+            sl = slice(ctx["n_app"], None)
             p_app = plan.pair_links.shape[0]
-            n_bg = ctx["pair_links"].shape[0] - p_app
-            bl, bf, bm = _pad_pairs(ctx["pair_links"][p_app:],
-                                    ctx["pair_fc"][p_app:],
-                                    _padded_len(n_bg, _PAIR_BUCKET_PLAN))
             tails = (_i32(ctx["safe"][sl]), _f32(ctx["valid"][sl]),
                      _f32(ctx["hops"][sl]), _i32(ctx["nic_ids"][sl]),
-                     bl, bf, bm)
-            bufs = dev.get("bufs")
-            if (bufs is not None and bufs[1].shape[0] == n_all
-                    and bufs[4].shape[0] == p_sorted + bl.shape[0]
-                    and (bufs[0].ndim == 2) == view):
-                # steady state: write ONLY the tails into the donated
-                # full-size buffers — the pinned head is never re-copied
-                dev["bufs"] = None       # donation consumes the olds
-                bufs = _tail_writer(n_app, p_sorted, view)(bufs, tails)
-            else:
-                bufs = tuple(
-                    jnp.concatenate([head, tail]) for head, tail in zip(
-                        (dev["safe"], dev["validf"], dev["hops"],
-                         dev["nic_ids"], dev["pair_links"],
-                         dev["pair_fc"], dev["pair_mask"]), tails))
-                if view:
-                    bufs = (_path_view(bufs[0]), *bufs[1:])
-            dev["bufs"] = bufs
-            (safe, validf, hops, nic_ids,
-             pair_links, pair_fc, pair_mask) = bufs
-        else:
-            safe, validf = dev["safe"], dev["validf"]
-            if view:
-                if "safe_view" not in dev:
-                    dev["safe_view"] = _path_view(safe)
-                safe = dev["safe_view"]
-            hops, nic_ids = dev["hops"], dev["nic_ids"]
-            pair_links, pair_fc = dev["pair_links"], dev["pair_fc"]
-            pair_mask = dev["pair_mask"]
+                     *_pad_pairs(ctx["pair_links"][p_app:],
+                                 ctx["pair_fc"][p_app:], tail))
+        (safe, validf, hops, nic_ids,
+         pair_links, pair_fc, pair_mask) = dev.buffers(tails, view)
+        seg_off, sched = dev.seg_off, dev.sched
     else:
         safe = _i32(ctx["safe"])
         if view:
@@ -558,11 +566,9 @@ def _prepare_inputs(sim, ctx: dict):
         hops = _f32(ctx["hops"])
         nic_ids = _i32(ctx["nic_ids"])
         pair_links, pair_fc, pair_mask = _pad_pairs(
-            ctx["pair_links"], ctx["pair_fc"],
-            _padded_len(ctx["pair_links"].shape[0], _PAIR_BUCKET_FULL))
-        seg_off = jnp.zeros(int(tp.n_links) + 1, dtype=jnp.int32)
+            ctx["pair_links"], ctx["pair_fc"], tail)
+        seg_off = jnp.zeros(n_links + 1, dtype=jnp.int32)
         sched = jnp.zeros(3, dtype=jnp.int32)
-        p_sorted = 0                     # planless: scatter everything
 
     cm = ctx["cand_mask"]
     inputs = (
@@ -580,25 +586,26 @@ def _prepare_inputs(sim, ctx: dict):
         _f32(p.qwait_fraction), _f32(p.stall_gain),
         _f32(tp.nic_latency_ns), _f32(tp.hop_latency_ns),
     )
-    statics = (int(ctx["gnoise"].shape[0]), int(tp.n_links), *mode,
-               p_sorted)
+    statics = _Statics(n_links, use_kernel, interpret, head)
+    n_spray = int(ctx["gnoise"].shape[0])
     # the score's and the observables' two, one a feedback iteration
     GATHER["kernel_slots" if view else "xla_slots"] += \
-        (3 + statics[0] - 1) * int(validf.size)
-    if mode[0]:
-        _count_segsum(statics, int(pair_links.shape[0]),
+        (3 + n_spray - 1) * int(validf.size)
+    if use_kernel:
+        _count_segsum(statics, n_spray, int(pair_links.shape[0]),
                       int(nic_ids.shape[0]),
                       0 if plan is None else int(plan.pair_links.shape[0]))
     return inputs, statics
 
 
-def _count_segsum(statics, n_pairs: int, n_rows: int, p_head: int):
+def _count_segsum(statics: _Statics, n_spray: int, n_pairs: int,
+                  n_rows: int, p_head: int):
     """Add one phase's Pallas segment sums to `SEGSUM`: the NIC sum over
     ``n_rows``, and each of the ``n_spray + 1`` pair reductions (the
     first spray, each feedback iteration, ``load_q``) over the sorted
     head and the unsorted rest of ``n_pairs``; and the head's ``p_head``
     real pairs with the zero-mask pairs that pad it, once a phase."""
-    n_spray, n_links, _, _, p_sorted = statics
+    n_links, p_sorted = statics.n_links, statics.p_sorted
     reductions = n_spray + 1
     steps = dense_grid_steps(n_rows, n_links)
     dense = 1
@@ -617,11 +624,9 @@ def _count_segsum(statics, n_pairs: int, n_rows: int, p_head: int):
 def batch_signature(sim, ctx: dict) -> tuple:
     """Hashable key: phases with equal keys (shapes + statics + mask
     presence) can share one vmapped dispatch."""
-    plan = ctx["plan"]
     return (int(sim.topo.n_links), int(ctx["gnoise"].shape[0]),
             *kernel_mode(sim.params), tuple(ctx["safe"].shape),
-            padded_pair_len(ctx),
-            0 if plan is None else _head_len(plan.pair_links.shape[0]),
+            _pair_layout(ctx["plan"], ctx["pair_links"].shape[0]),
             ctx["cand_mask"] is not None)
 
 
@@ -654,8 +659,7 @@ def fixed_point_jax(sim, ctx: dict):
     phase = ctx["phase"]
     with sim.stage("transfer", phase):
         inputs, statics = _prepare_inputs(sim, ctx)
-    fn = _jitted_pipeline(*statics, batched=False,
-                          has_mask=ctx["cand_mask"] is not None)
+    fn = _jitted_pipeline(statics, batched=False)
     out = _dispatch(sim, phase, fn, inputs)
     PIPELINE_CALLS["single"] += 1
     return _fetch(sim, phase, out)
@@ -675,15 +679,9 @@ def fixed_point_jax_batch(batch):
     sim, phase = batch[0][0], batch[0][1]["phase"]
     with sim.stage("transfer", phase):
         prepped = [_prepare_inputs(s, ctx) for s, ctx in batch]
-        statics = prepped[0][1]
-        has_mask = batch[0][1]["cand_mask"] is not None
-        stacked = []
-        for j, col in enumerate(zip(*(inp for inp, _ in prepped))):
-            if j == _MASK_ARG and not has_mask:
-                stacked.append(None)
-                continue
-            stacked.append(jnp.stack(col))
-    fn = _jitted_pipeline(*statics, batched=True, has_mask=has_mask)
+        stacked = jax.tree.map(lambda *col: jnp.stack(col),
+                               *(inputs for inputs, _ in prepped))
+    fn = _jitted_pipeline(prepped[0][1], batched=True)
     outs = _fetch(sim, phase, _dispatch(sim, phase, fn, stacked))
     PIPELINE_CALLS["batched"] += 1
     return [tuple(o[b] for o in outs) for b in range(len(batch))]

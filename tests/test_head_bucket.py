@@ -112,5 +112,5 @@ def test_two_placements_in_one_bucket_compile_once():
     with _Compiles() as compiles:
         sig_b = _signature_of_two_phases(sim, *b)
     assert sig_a == sig_b
-    assert a[3].device_bundle["p_sorted"] == b[3].device_bundle["p_sorted"]
+    assert a[3].device_bundle.p_sorted == b[3].device_bundle.p_sorted
     assert compiles.n == 0

@@ -204,7 +204,7 @@ def test_segsum_counts_a_reused_plan_and_a_planless_phase():
     # 300 app + 16 background rows; 5 pair reductions a phase (4 sprays
     # + load_q); 2 link blocks
     (_, plan), reused = delta(lambda: _reused_plan_phases("on", 1))
-    p_head = plan.device_bundle["p_sorted"]
+    p_head = plan.device_bundle.p_sorted
     p_real = int(plan.pair_links.shape[0])
     assert reused == {"sorted_calls": 5, "dense_calls": 1 + 5,
                       "grid_steps": 2 + 5 * (p_head // 1024 + 2) + 5 * 2,
@@ -349,6 +349,80 @@ def test_run_phase_batch_uses_one_vmapped_dispatch():
     run_phase_batch([(sim, kw) for sim, kw in _batch_calls("jax")])
     assert PIPELINE_CALLS["batched"] == before["batched"] + 1
     assert PIPELINE_CALLS["single"] == before["single"]
+
+
+def test_run_phase_batch_of_faulted_phases_matches_sequential():
+    """Phases with a fault candidate mask share one vmapped dispatch,
+    the mask stacked like every other input, and match per-phase
+    dispatch."""
+    from repro.dragonfly.jax_backend import PIPELINE_CALLS
+
+    def faulted():
+        calls = _batch_calls("jax")
+        for sim, _ in calls:
+            sim.set_faults(FaultSchedule.of(
+                link_down([1, TOPO.n_links // 2], start=0)))
+        return calls
+
+    before = dict(PIPELINE_CALLS)
+    batched = run_phase_batch(faulted())
+    assert PIPELINE_CALLS["batched"] == before["batched"] + 1
+    assert PIPELINE_CALLS["single"] == before["single"]
+    sequential = [sim.run_phase(**kw) for sim, kw in faulted()]
+    for rb, rs in zip(batched, sequential):
+        assert rb.stranded is not None
+        assert np.array_equal(rb.stranded, rs.stranded)
+        assert np.array_equal(rb.t_us, rs.t_us)
+        assert np.array_equal(rb.latency_us, rs.latency_us)
+        assert np.array_equal(rb.flits, rs.flits)
+
+
+def _signed_inputs(sim, n_phases, plan=None):
+    """(`batch_signature`, the pipeline inputs' shapes and dtypes with
+    the statics) of ``n_phases`` phases, prepared and not run."""
+    import jax
+
+    from repro.dragonfly import jax_backend
+    src, dst, size = _flows(TOPO, seed=42)
+    out = []
+    for _ in range(n_phases):
+        ctx = sim._phase_begin(src, dst, size,
+                               RoutingPolicy(RoutingMode.ADAPTIVE_0),
+                               plan=plan)
+        try:
+            inputs, statics = jax_backend._prepare_inputs(sim, ctx)
+            shapes = jax.tree.map(lambda a: (a.shape, a.dtype), inputs)
+            out.append((jax_backend.batch_signature(sim, ctx),
+                        (shapes, statics)))
+        finally:
+            ctx["stage"].stop()
+    return out
+
+
+def test_batch_signatures_agree_exactly_when_input_shapes_do():
+    """One pair layout: over plan-reused phases with and without
+    background rows, with `safe` as ids and as its index view, planless
+    phases, and a plan whose background pairs fall on either side of
+    the 1024-pair tail bucket's edge, two phases' `batch_signature`s
+    are equal exactly when their pipeline inputs' shapes, dtypes and
+    statics are."""
+    src, dst, size = _flows(TOPO, seed=42)
+
+    def sim(**kw):
+        return DragonflySimulator(TOPO, SimParams(seed=1, backend="jax",
+                                                  **kw))
+
+    # 48 background flows draw 943-1,098 pairs a phase with this seed
+    edge = sim(bg_flows_per_phase=48)
+    edge_phases = _signed_inputs(edge, 4, edge.plan_for(src, dst, size))
+    assert len({sig for sig, _ in edge_phases}) == 2
+    quiet, view = sim(bg_enable=False), sim(pallas_kernel="on")
+    phases = edge_phases + _signed_inputs(
+        quiet, 2, quiet.plan_for(src, dst, size)) + _signed_inputs(
+        view, 2, view.plan_for(src, dst, size)) + _signed_inputs(sim(), 2)
+    for sig_a, shapes_a in phases:
+        for sig_b, shapes_b in phases:
+            assert (sig_a == sig_b) == (shapes_a == shapes_b)
 
 
 # --------------------------------------------------------------------------
